@@ -22,8 +22,10 @@ import numpy as np
 from repro.core import codec as wire_codec
 from repro.core.plans import plan_assignment
 from repro.core.sharding_alg import Assignment, NeighborLink
+from repro.kernels import ops as kernel_ops
 from repro.optim.compression import (
     Q_BLOCK,
+    ROUNDTRIP_REL_SLACK,
     compressed_bytes,
     int8_dequantize,
     int8_quantize,
@@ -151,23 +153,17 @@ class EncodedLeaf:
     raw: Optional[np.ndarray] = None
 
 
-def _kernel_encode_matches(xf_blocks: np.ndarray, codes: np.ndarray,
-                           scales: np.ndarray) -> bool:
-    """Run the Pallas shard codec on the padded block view and assert it is
-    bit-identical to the jnp reference (codes AND scales). Returns False —
-    without failing the encode — only when Pallas itself is unavailable in
-    this runtime; a completing kernel that disagrees is a hard error."""
-    try:
-        from repro.kernels.shard_codec import shard_encode_kernel
-        kc, ks = shard_encode_kernel(xf_blocks)
-    except ImportError:  # pragma: no cover - pallas missing entirely
-        return False
-    kc, ks = np.asarray(kc), np.asarray(ks)
-    assert np.array_equal(kc, np.asarray(codes)), \
-        "shard_encode_kernel codes diverged from int8_quantize reference"
-    assert np.array_equal(ks, np.asarray(scales)), \
-        "shard_encode_kernel scales diverged from int8_quantize reference"
-    return True
+def _kernel_encode_matches(xf_blocks, codes: np.ndarray,
+                           scales: np.ndarray) -> None:
+    """Run the Pallas shard codec on the padded block view and require it to
+    be bit-identical to the jnp reference (codes AND scales)."""
+    kc, ks = kernel_ops.shard_encode(xf_blocks)
+    if not np.array_equal(np.asarray(kc), codes):
+        raise AssertionError(
+            "shard_encode kernel codes diverged from int8_quantize reference")
+    if not np.array_equal(np.asarray(ks), scales):
+        raise AssertionError(
+            "shard_encode kernel scales diverged from int8_quantize reference")
 
 
 def encode_state(tree, codec: str = wire_codec.CODEC_INT8,
@@ -212,7 +208,7 @@ def decode_state(leaves: Sequence[EncodedLeaf], manifest: StateManifest,
     """Inverse of :func:`encode_state`: rebuild the pytree on the joining
     node. int8 leaves decode through ``int8_dequantize`` (fp32-exact
     ``code * scale``), with the Pallas decode kernel cross-checked
-    bit-for-bit when available. Every decoded fp32 element satisfies
+    bit-for-bit. Every decoded fp32 element satisfies
     ``|decoded - original| <= scale_of_its_block / 2``."""
     arrs = []
     for e in leaves:
@@ -222,17 +218,12 @@ def decode_state(leaves: Sequence[EncodedLeaf], manifest: StateManifest,
         dec = np.asarray(int8_dequantize(jnp.asarray(e.codes),
                                          jnp.asarray(e.scales), e.meta))
         if verify_kernel:
-            try:
-                from repro.kernels.shard_codec import shard_decode_kernel
-                kd = np.asarray(shard_decode_kernel(
-                    jnp.asarray(e.codes), jnp.asarray(e.scales)))
-            except ImportError:  # pragma: no cover - pallas missing
-                kd = None
-            if kd is not None:
-                n = dec.size
-                assert np.array_equal(kd.reshape(-1)[:n],
-                                      dec.reshape(-1).astype(np.float32)), \
-                    "shard_decode_kernel diverged from int8_dequantize"
+            kd = np.asarray(kernel_ops.shard_decode(jnp.asarray(e.codes),
+                                                    jnp.asarray(e.scales)))
+            if not np.array_equal(kd.reshape(-1)[:dec.size],
+                                  dec.reshape(-1).astype(np.float32)):
+                raise AssertionError(
+                    "shard_decode kernel diverged from int8_dequantize")
         arrs.append(dec)
     return jax.tree_util.tree_unflatten(manifest.treedef, arrs)
 
@@ -241,8 +232,8 @@ def roundtrip_max_error_ok(tree, decoded_tree,
                            leaves: Sequence[EncodedLeaf]) -> bool:
     """Check the documented bound: every int8-encoded fp32 element is within
     ``scale/2`` of the original (raw leaves must match exactly). The bound
-    gets a 1e-5 relative slack for fp32 rounding of the quantize ratio and
-    the ``code * scale`` reconstruction (see int8_dequantize's contract)."""
+    gets ``ROUNDTRIP_REL_SLACK`` for fp32 rounding of the ``code * scale``
+    reconstruction (see int8_dequantize's contract)."""
     orig = jax.tree_util.tree_leaves(tree)
     dec = jax.tree_util.tree_leaves(decoded_tree)
     for o, d, e in zip(orig, dec, leaves):
@@ -255,7 +246,7 @@ def roundtrip_max_error_ok(tree, decoded_tree,
         pad = (-err.size) % Q_BLOCK
         err = np.pad(err, (0, pad)).reshape(-1, Q_BLOCK)
         bound = np.asarray(e.scales)[:, None] / 2.0
-        if not np.all(err <= bound * (1.0 + 1e-5)):
+        if not np.all(err <= bound * (1.0 + ROUNDTRIP_REL_SLACK)):
             return False
     return True
 

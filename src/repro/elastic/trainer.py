@@ -14,6 +14,8 @@ synchronous data-parallel training over a device mesh that grows and shrinks
     a failed device additionally exercises the MemoryReplicaStore restore;
   * per-mesh-size compiled train steps are cached, so churn costs one
     compile the first time a given cluster size appears (then it's free);
+    that compile is timed on its own (``compile_seconds``), apart from the
+    step times;
   * each node brings its data split (paper §VI-A): the loader reshard hook
     is invoked on every membership change;
   * link events from replayed scenario traces (degrade / sever / restore)
@@ -24,8 +26,9 @@ synchronous data-parallel training over a device mesh that grows and shrinks
     outliers to the monitor for scale-in recommendation (τ^sync-aware shard
     planning already derates slow nodes during scale-out).
 
-Run under ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` for a
-multi-device CPU demonstration (examples/elastic_training.py).
+It runs on whatever devices it is given: TPU chips (``chip_smoke.py``), or
+CPU devices under ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` for
+a multi-device CPU demonstration (examples/elastic_training.py).
 """
 from __future__ import annotations
 
@@ -95,6 +98,8 @@ class ElasticTrainer:
         # don't clobber each other; the slowest surviving impairment wins.
         self._link_overrides: Dict[int, Dict[object, NeighborLink]] = {}
         self._step_fns: Dict[tuple, Callable] = {}
+        #: seconds spent compiling the train step, per (n, tp)
+        self.compile_seconds: Dict[tuple, float] = {}
         # Current parallelism layout: tp-ways of tensor parallelism over the
         # active devices (1 = the pure-DP layout every pre-reshard trainer
         # ran — meshes, shardings and compiled steps are then bit-identical
@@ -264,27 +269,37 @@ class ElasticTrainer:
             self.on_reshard(self.device_ids())
         return self.state
 
-    def _get_step_fn(self, n: int):
+    def _get_step_fn(self, n: int, batch):
+        """The jitted step for ``(n, tp)``, compiled ahead of its first call
+        (the call then reuses that executable) so that the compile is timed
+        apart from the step."""
         key = (n, self._tp)
         if key not in self._step_fns:
             step = self.model.make_train_step()
             state_sh = self._state_shardings()
-            self._step_fns[key] = jax.jit(
+            fn = jax.jit(
                 step,
                 in_shardings=(state_sh, self._batch_sharding()),
                 out_shardings=(state_sh, None),
             )
+            t0 = time.perf_counter()
+            fn.lower(self.state, batch).compile()
+            self.compile_seconds[key] = time.perf_counter() - t0
+            self._step_fns[key] = fn
         return self._step_fns[key]
 
     def step(self, batch: dict):
-        """batch arrays lead with global_batch (= per_device × n_active)."""
+        """batch arrays lead with global_batch (= per_device × n_active).
+        The recorded step time runs from dispatch until the new state is
+        ready on the devices; it excludes the compile."""
         n = len(self.active)
-        fn = self._get_step_fn(n)
         batch = jax.device_put(batch, self._batch_sharding())
+        fn = self._get_step_fn(n, batch)
         t0 = time.perf_counter()
         self.state, metrics = fn(self.state, batch)
-        metrics = jax.tree.map(float, metrics)
+        jax.block_until_ready(self.state)
         dt = time.perf_counter() - t0
+        metrics = jax.tree.map(float, metrics)
         self._step_times.setdefault(n, []).append(dt)
         self.step_count += 1
         return metrics
@@ -297,8 +312,8 @@ class ElasticTrainer:
 
         Under a non-``none`` codec (standing policy or per-call override)
         the fp32 state buffers are int8-encoded and decoded through the
-        shard codec (Pallas kernel, jnp reference fallback — equivalence
-        asserted) to account wire bytes and validate the ``scale/2``
+        shard codec (the Pallas kernel, required to match the jnp reference
+        bit for bit) to account wire bytes and validate the ``scale/2``
         round-trip bound; the state installed on the mesh stays exact."""
         eff_codec = self.codec if codec is None else wire_codec.validate_policy(codec)
         candidates = [d for d in self.pool if d not in self.active]
@@ -502,7 +517,7 @@ class ElasticTrainer:
         step-time EWMA per cluster size (the control-plane hook)."""
         out = {}
         for n, times in self._step_times.items():
-            arr = np.asarray(times[1:] or times)  # drop compile step
+            arr = np.asarray(times[1:] or times)  # drop the warm-up step
             out[n] = {"mean_s": float(arr.mean()), "p95_s": float(np.percentile(arr, 95)),
                       "n_steps": len(arr)}
         return out

@@ -80,6 +80,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention_kernel(
     q, k, v, *,
+    interpret: bool,
     scale: float,
     softcap: float = 0.0,
     kind: str = "causal",
@@ -88,7 +89,6 @@ def flash_attention_kernel(
     q_offset: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
 ):
     """q: (B,Sq,H,hd); k,v: (B,Skv,K,hd) with H % K == 0. Returns (B,Sq,H,hd)."""
     B, Sq, H, hd = q.shape

@@ -1,16 +1,19 @@
-"""Jitted public wrappers for the Pallas kernels.
+"""Public wrappers for the Pallas kernels — the only place that picks
+``interpret``.
 
-On CPU (this container) kernels run with ``interpret=True`` — the kernel body
-executes exactly, block by block, validating the TPU program. On a TPU
-runtime the same calls compile to Mosaic. ``use_pallas=True`` paths in the
-models route here.
+Where JAX's default backend is a TPU the kernels compile to Mosaic. On any
+other backend (the CPU test runs, ``JAX_PLATFORMS=cpu``) they run with
+``interpret=True``: the kernel body executes exactly, block by block, which
+checks the TPU program's logic but says nothing about its speed. The kernel
+modules have no ``interpret`` default, so every caller on the training path
+comes through here. ``use_pallas=True`` paths in the models route here too.
 
 Autodiff: ``pallas_call`` with carried VMEM scratch has no JVP rule, so each
 kernel is wrapped in ``jax.custom_vjp`` whose backward differentiates the
 mathematically-identical XLA path (models/layers.blocked_attention,
 models/rwkv6.wkv6_chunked, models/mamba2.ssd_chunked) — forward speed from
 the kernel, exact gradients from XLA. A fused backward kernel is the
-documented next step for real-TPU perf work (EXPERIMENTS.md §Perf).
+next step for TPU performance work.
 """
 from __future__ import annotations
 
@@ -162,9 +165,13 @@ def ssd(x, dt, A_log, Bm, Cm, state=None, *, chunk=64):
 # ---------------------------------------------------------------------------
 
 
+@jax.jit
 def shard_encode(x_blocks):
+    """(nb, 256) fp32 → (int8 codes (nb, 256), fp32 scales (nb,))."""
     return _codec.shard_encode_kernel(x_blocks, interpret=_interpret())
 
 
+@jax.jit
 def shard_decode(codes, scales):
+    """Inverse of :func:`shard_encode`: ``codes * scales`` in fp32."""
     return _codec.shard_decode_kernel(codes, scales, interpret=_interpret())

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.models.layers import MaskSpec, _mask_block
+from repro.optim.compression import round_quotient
 
 
 # ---------------------------------------------------------------------------
@@ -102,7 +103,8 @@ def shard_codec_ref(x_blocks):
     # Pallas kernel bit-for-bit regardless of how a lowering handles the
     # division (see optim/compression.int8_quantize).
     scale = jnp.maximum(jnp.max(jnp.abs(x_blocks), axis=1), 1e-12) * (1.0 / 127.0)
-    codes = jnp.clip(jnp.round(x_blocks / scale[:, None]), -127, 127).astype(jnp.int8)
+    codes = jnp.clip(round_quotient(x_blocks, scale[:, None]), -127,
+                     127).astype(jnp.int8)
     return codes, scale
 
 

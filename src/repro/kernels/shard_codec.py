@@ -1,31 +1,36 @@
 """Shard codec Pallas-TPU kernel: per-block int8 quantization of replication
 payloads (paper §III — state shards shipped to a joining node; quantizing the
 optimizer-moment shards cuts replication bytes ~4× with negligible recovery
-error, a beyond-paper optimization recorded in EXPERIMENTS.md §Perf).
+error, a beyond-paper optimization).
 
 Encode: (nb, 256) fp32 → int8 codes + fp32 per-block scales.
 Decode: inverse. Grid over block rows; everything VMEM-resident.
+
+Callers go through ``repro.kernels.ops``, which picks ``interpret``.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.optim.compression import round_quotient
+
 Q_BLOCK = 256
 
+#: Block height of a grid step. int8 arrays tile as (32, 128) on the TPU, so a
+#: block shorter than the array must be a multiple of 32 rows (which also
+#: covers fp32's (8, 128)).
+ROWS_PER_BLOCK = 256
 
-def _block_rows(nb: int, rows_per_block: int) -> int:
-    """Rows per grid step: the largest divisor of ``nb`` that fits in
-    ``rows_per_block``. Awkward row counts (nb prime, or just off a power of
-    two) still get multi-row blocks — e.g. nb=300 → 150 rows — instead of
-    collapsing to single-row blocks (300 grid steps of 1 row each)."""
-    r = min(rows_per_block, nb)
-    while nb % r:
-        r -= 1
-    return r
+
+def _block_rows(nb: int) -> int:
+    """Rows per grid step. An array of at most ``ROWS_PER_BLOCK`` rows is one
+    block (a block equal to the whole array is always legal); a taller one
+    gets ``ROWS_PER_BLOCK``-row blocks over a ``cdiv`` grid, whose ragged last
+    block Pallas pads on input and masks on output. Quantization is row-local,
+    so the padding rows never touch a real row's scale or codes."""
+    return min(nb, ROWS_PER_BLOCK)
 
 
 def _encode_kernel(x_ref, codes_ref, scale_ref):
@@ -34,7 +39,7 @@ def _encode_kernel(x_ref, codes_ref, scale_ref):
     # this by a given lowering; spelling it out keeps scales bit-identical to
     # the jnp references (ref.shard_codec_ref, compression.int8_quantize).
     scale = jnp.maximum(jnp.max(jnp.abs(x), axis=1, keepdims=True), 1e-12) * (1.0 / 127.0)
-    codes = jnp.clip(jnp.round(x / scale), -127, 127)
+    codes = jnp.clip(round_quotient(x, scale), -127, 127)
     codes_ref[...] = codes.astype(jnp.int8)
     scale_ref[...] = scale
 
@@ -43,16 +48,14 @@ def _decode_kernel(codes_ref, scale_ref, x_ref):
     x_ref[...] = codes_ref[...].astype(jnp.float32) * scale_ref[...]
 
 
-def shard_encode_kernel(x_blocks, *, rows_per_block: int = 256,
-                        interpret: bool = True):
-    """x_blocks: (nb, 256) fp32 → (codes int8 (nb,256), scales fp32 (nb,1))."""
+def shard_encode_kernel(x_blocks, *, interpret: bool):
+    """x_blocks: (nb, 256) fp32 → (codes int8 (nb,256), scales fp32 (nb,))."""
     nb, w = x_blocks.shape
     assert w == Q_BLOCK
-    r = _block_rows(nb, rows_per_block)
-    grid = (nb // r,)
+    r = _block_rows(nb)
     codes, scales = pl.pallas_call(
         _encode_kernel,
-        grid=grid,
+        grid=(pl.cdiv(nb, r),),
         in_specs=[pl.BlockSpec((r, w), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((r, w), lambda i: (i, 0)),
@@ -67,14 +70,12 @@ def shard_encode_kernel(x_blocks, *, rows_per_block: int = 256,
     return codes, scales[:, 0]
 
 
-def shard_decode_kernel(codes, scales, *, rows_per_block: int = 256,
-                        interpret: bool = True):
+def shard_decode_kernel(codes, scales, *, interpret: bool):
     nb, w = codes.shape
-    r = _block_rows(nb, rows_per_block)
-    grid = (nb // r,)
+    r = _block_rows(nb)
     out = pl.pallas_call(
         _decode_kernel,
-        grid=grid,
+        grid=(pl.cdiv(nb, r),),
         in_specs=[
             pl.BlockSpec((r, w), lambda i: (i, 0)),
             pl.BlockSpec((r, 1), lambda i: (i, 0)),

@@ -63,8 +63,8 @@ def _ssd_kernel(x_ref, dt_ref, l_ref, b_ref, c_ref, h0_ref, y_ref, hf_ref,
         hf_ref[0] = h_new
 
 
-def ssd_kernel(x, dt, A_log, Bm, Cm, state=None, *, chunk: int = 64,
-               interpret: bool = True):
+def ssd_kernel(x, dt, A_log, Bm, Cm, state=None, *, interpret: bool,
+               chunk: int = 64):
     """x: (B,S,H,P); dt: (B,S,H) > 0; A_log: (H,); Bm,Cm: (B,S,N).
     Returns (y (B,S,H,P) fp32, final_state (B,H,P,N) fp32)."""
     B, S, H, P = x.shape

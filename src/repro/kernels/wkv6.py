@@ -65,8 +65,8 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sf_ref,
         sf_ref[0] = S_new
 
 
-def wkv6_kernel(r, k, v, lw, u, state=None, *, chunk: int = 64,
-                interpret: bool = True):
+def wkv6_kernel(r, k, v, lw, u, state=None, *, interpret: bool,
+                chunk: int = 64):
     """r,k,v,lw: (B,S,H,hd); u: (H,hd); state: (B,H,hd,hd) fp32 or None.
     Returns (out (B,S,H,hd) fp32, final_state (B,H,hd,hd) fp32)."""
     B, S, H, hd = r.shape

@@ -1,15 +1,17 @@
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-"""Multi-pod dry-run (task spec MULTI-POD DRY-RUN steps 0-4).
+"""Multi-pod dry-run.
 
 For every (architecture × shape cell × mesh) combination this lowers and
 compiles the real train_step / prefill / decode_step under production
 shardings, prints memory_analysis() and cost_analysis(), parses the
 post-SPMD HLO for collective wire bytes, and derives the three roofline
-terms (§ROOFLINE ANALYSIS). Results accumulate in
-benchmarks/results/dryrun*.json for EXPERIMENTS.md and the roofline report.
+terms for the chip the production meshes are sized for
+(``TARGET_DEVICE_KIND``, a key of ``DEVICE_PEAKS``). The roofline is a model
+of that chip from the compiled program's counts, whatever devices compiled
+it; it is never a measurement. Results accumulate in
+benchmarks/results/dryrun*.json for the roofline report.
+
+The production meshes need 256/512 devices; on a host without them the CLI
+asks XLA's CPU backend for 512 virtual devices unless ``XLA_FLAGS`` is set.
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch tinyllama-1.1b \
         --shape train_4k --mesh single
@@ -18,6 +20,7 @@ benchmarks/results/dryrun*.json for EXPERIMENTS.md and the roofline report.
 import argparse
 import json
 import math
+import os
 import re
 import time
 from pathlib import Path
@@ -32,10 +35,24 @@ from repro.models import build_model
 from repro.models import sharding as SH
 from repro.models.shardctx import activation_sharding
 
-# TPU v5e constants (task spec).
-PEAK_FLOPS = 197e12  # bf16 FLOP/s per chip
-HBM_BW = 819e9  # B/s per chip
-LINK_BW = 50e9  # B/s per ICI link
+#: Per-chip peaks keyed by ``jax.Device.device_kind``: bf16 FLOP/s, HBM
+#: bytes/s, and bytes/s of one ICI link. Source: Google Cloud documentation,
+#: "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of chip-to-chip
+#: interconnect over 4 links = 50 GB/s per link).
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+#: the chip the production meshes (16×16 per pod) are sized for
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
+def device_peaks(device_kind: str) -> dict:
+    """The peaks of ``device_kind``; a kind not in the table is an error."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peaks for device kind {device_kind!r}; known: "
+                       f"{sorted(DEVICE_PEAKS)}") from None
 
 RESULTS_DIR = Path(__file__).resolve().parents[3] / "benchmarks" / "results"
 
@@ -223,11 +240,12 @@ def run_cell(arch: str, shape: str, mesh_kind: str, *, debug=False,
             "unknown_trip_counts": t.unknown_trip,
         }
         rec["hlo_bytes"] = len(hlo)
-    rec.update(_roofline(rec, cfg, cell, n_dev))
+    rec.update(_roofline(rec, cfg, cell, n_dev, TARGET_DEVICE_KIND))
     return rec
 
 
-def _roofline(rec, cfg, cell, n_dev) -> dict:
+def _roofline(rec, cfg, cell, n_dev, device_kind) -> dict:
+    peaks = device_peaks(device_kind)
     # Loop-aware HLO analysis (preferred); raw cost_analysis kept for
     # reference (it counts scan bodies once — see hlo_analysis.py).
     ha = rec.get("hlo_analysis")
@@ -240,9 +258,9 @@ def _roofline(rec, cfg, cell, n_dev) -> dict:
         flops_dev = cost.get("flops") or 0.0
         bytes_dev = cost.get("bytes accessed") or 0.0
         wire_dev = 0.0
-    compute_s = flops_dev / PEAK_FLOPS
-    memory_s = bytes_dev / HBM_BW
-    collective_s = wire_dev / LINK_BW
+    compute_s = flops_dev / peaks["flops"]
+    memory_s = bytes_dev / peaks["hbm_bw"]
+    collective_s = wire_dev / peaks["link_bw"]
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     dominant = max(terms, key=terms.get)
     train = cell.kind == "train"
@@ -250,6 +268,7 @@ def _roofline(rec, cfg, cell, n_dev) -> dict:
     model_flops = cfg.model_flops_per_token(train=train) * tokens
     hlo_global = flops_dev * n_dev
     return {"roofline": {
+        "device_kind": device_kind,
         "compute_s": compute_s,
         "memory_s": memory_s,
         "collective_s": collective_s,
@@ -271,6 +290,8 @@ def main():
                     help="tiny mesh (needs only 8 devices) for smoke tests")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=512")
 
     archs = list(ASSIGNED) if (args.all or not args.arch) else [args.arch]
     shapes = list(SHAPES) if (args.all or not args.shape) else [args.shape]

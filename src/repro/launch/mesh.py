@@ -16,6 +16,7 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
 
 from repro.core.plans import ParallelismPlan
 
@@ -32,7 +33,11 @@ def mesh_from_plan(plan: ParallelismPlan,
     enumeration (e.g. the elastic trainer's surviving-device list); its
     length must equal ``plan.n_devices``."""
     if devices is None:
-        return jax.make_mesh(plan.shape, plan.axes)
+        # Auto axes: the model code places activations with
+        # with_sharding_constraint, which refuses Explicit axes (JAX 0.9's
+        # make_mesh default).
+        return jax.make_mesh(plan.shape, plan.axes,
+                             axis_types=(AxisType.Auto,) * len(plan.axes))
     import numpy as np
     arr = np.asarray(devices, dtype=object)
     if arr.size != plan.n_devices:

@@ -14,13 +14,10 @@ Modes:
 
 Scale knobs live in the config (`repro/configs/<arch>.py`); per-run reduction
 uses the same `reduced()` family transform the smoke tests use, so the
-launcher runs anywhere while staying architecturally faithful.
+launcher runs anywhere while staying architecturally faithful. On a CPU
+host, ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` gives it eight
+devices to scale over.
 """
-import os
-
-if "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-
 import argparse
 import dataclasses
 import sys
@@ -32,6 +29,7 @@ import numpy as np
 from repro.configs import SHAPES, get_config
 from repro.configs.base import ShapeCell
 from repro.data.synthetic import TokenStream, make_train_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 
 
@@ -51,6 +49,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--lower-only", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.lower_only:
         from repro.launch import dryrun
@@ -76,7 +75,8 @@ def main():
     if args.elastic:
         from repro.elastic import ElasticTrainer
 
-        trainer = ElasticTrainer(model, initial=max(2, len(jax.devices()) // 2),
+        n_dev = len(jax.devices())
+        trainer = ElasticTrainer(model, initial=min(n_dev, max(2, n_dev // 2)),
                                  per_device_batch=max(1, cell.global_batch // 8))
         trainer.init()
         stream = TokenStream(vocab=cfg.vocab, seq_len=cell.seq_len, seed=0)

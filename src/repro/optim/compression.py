@@ -15,6 +15,41 @@ import jax.numpy as jnp
 
 Q_BLOCK = 256
 
+#: Relative slack on the ``scale / 2`` round-trip bound of
+#: :func:`int8_dequantize`, from fp32 arithmetic. Codes are the exactly
+#: rounded quotient (:func:`round_quotient`), so the only error past
+#: ``scale / 2`` is the reconstruction ``code * scale``, which rounds by at
+#: most 2**-24 * 127 * scale, i.e. 254 * 2**-24 of the bound; the f32
+#: subtraction and comparison that check it add 2**-24 each. That is under
+#: 2**-16 of the bound; the slack is twice that.
+ROUNDTRIP_REL_SLACK = 2.0 ** -15
+
+
+def round_quotient(x, scale):
+    """``round_half_even(x / scale)`` of the exact quotient, for f32 ``x``
+    and positive f32 ``scale`` (broadcast against ``x``) with
+    ``|x / scale| <= 127.5``. An f32 division need not be correctly rounded
+    (a TPU's is off by up to a few ulps), and even a correctly rounded one
+    can land on a half-integer the exact quotient misses; either changes
+    codes near a half-code. So the code from the division is checked against
+    the half-codes on both sides: ``scale`` is split into two 12-bit halves,
+    which makes every product with a half-code exact and the sign of
+    ``x - h * scale`` exact. Plain jnp, so the Pallas kernel and the XLA
+    references run the same arithmetic and agree on every device."""
+    c = jnp.round(x / scale)
+    hi = jax.lax.bitcast_convert_type(
+        jax.lax.bitcast_convert_type(scale, jnp.int32) & -4096, jnp.float32)
+    lo = scale - hi
+
+    def past(h):  # sign of x - h * scale
+        return (x - h * hi) - h * lo
+
+    up, down = past(c + 0.5), past(c - 0.5)
+    code = jnp.where(up > 0, c + 1, jnp.where(down < 0, c - 1, c))
+    # an exact tie goes to the even neighbour
+    code = jnp.where(up == 0, 2 * jnp.round(0.5 * (c + 0.5)), code)
+    return jnp.where(down == 0, 2 * jnp.round(0.5 * (c - 0.5)), code)
+
 
 def topk_compress_ef(grads, residual, k_frac: float = 0.01):
     """Top-|k| sparsification with error feedback.
@@ -49,8 +84,9 @@ def int8_quantize(x, block: int = Q_BLOCK):
 
     This is the jnp reference for ``kernels/shard_codec.shard_encode_kernel``:
     identical per-block scale formula (max-abs times the fp32 constant 1/127,
-    with a 1e-12 floor) and identical rounding, so codes and scales are
-    **bit-identical** between the two (the pairing property test in
+    with a 1e-12 floor) and identical rounding (:func:`round_quotient`, the
+    exactly rounded quotient), so codes and scales are
+    **bit-identical** between the two on every device (the pairing property test in
     tests/test_codec.py pins this down). The scale is written as an explicit
     reciprocal multiply — a single well-defined fp32 op — because ``/ 127.0``
     is at the compiler's mercy: one lowering keeps the true division, another
@@ -61,20 +97,19 @@ def int8_quantize(x, block: int = Q_BLOCK):
     pad = (-n) % block
     xf = jnp.pad(x.astype(jnp.float32).reshape(-1), (0, pad)).reshape(-1, block)
     scale = jnp.maximum(jnp.max(jnp.abs(xf), axis=1), 1e-12) * (1.0 / 127.0)
-    codes = jnp.clip(jnp.round(xf / scale[:, None]), -127, 127).astype(jnp.int8)
+    codes = jnp.clip(round_quotient(xf, scale[:, None]), -127, 127).astype(jnp.int8)
     return codes, scale, (x.shape, x.dtype)
 
 
 def int8_dequantize(codes, scale, meta, block: int = Q_BLOCK):
     """Inverse of :func:`int8_quantize`, with a documented error guarantee.
 
-    **Max-error bound**: quantization is round-to-nearest inside each block,
-    so for fp32 inputs every element satisfies
+    **Max-error bound**: codes are the exactly rounded quotient inside each
+    block, so for fp32 inputs every element satisfies
     ``|dequantized - original| <= scale_of_its_block / 2`` up to fp32
-    rounding of the ``x / scale`` ratio and of the ``code * scale``
-    reconstruction — a few ulps of the bound, never more (checked with a
-    1e-5 relative slack in ``repro.core.replication.roundtrip_max_error_ok``
-    and in tests).
+    rounding of the ``code * scale`` reconstruction: at most
+    ``ROUNDTRIP_REL_SLACK`` of the bound (checked in
+    ``repro.core.replication.roundtrip_max_error_ok``).
 
     The bound is stated in fp32 — reconstruction happens in fp32 and only
     the **final** cast goes to the original dtype, so for a non-fp32 input

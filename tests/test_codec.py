@@ -271,30 +271,34 @@ def test_sync_payload_compresses_with_scheduler_policy():
 
 
 # ---------------------------------------------------------------------------
-# Satellite 1: shard-codec grid fallback on awkward block counts.
+# Shard-codec grid on awkward block counts.
 # ---------------------------------------------------------------------------
 
 
 def test_block_rows_largest_divisor_within_cap():
-    from repro.kernels.shard_codec import _block_rows
+    """Block heights the TPU accepts: the whole array when it fits in one
+    block, else the cap (a multiple of the int8 tile's 32 rows) over a
+    ragged ``cdiv`` grid. Divisor search gave illegal heights (nb=300 → 150,
+    the GPT-2 embedding's 150,771 blocks → 87)."""
+    from repro.kernels.shard_codec import ROWS_PER_BLOCK, _block_rows
 
-    assert _block_rows(300, 256) == 150
-    assert _block_rows(510, 256) == 255
-    assert _block_rows(1000, 256) == 250
-    assert _block_rows(7, 256) == 7
-    assert _block_rows(64, 256) == 64
-    assert _block_rows(257, 256) == 1  # prime > cap: nothing divides
+    assert ROWS_PER_BLOCK == 256 and ROWS_PER_BLOCK % 32 == 0
+    assert _block_rows(300) == 256
+    assert _block_rows(510) == 256
+    assert _block_rows(150_771) == 256
+    assert _block_rows(7) == 7
+    assert _block_rows(64) == 64
+    assert _block_rows(257) == 256
     for nb in (1, 2, 3, 5, 12, 30, 97, 300, 510, 777, 1000):
-        r = _block_rows(nb, 256)
-        assert 1 <= r <= min(256, nb)
-        assert nb % r == 0
+        r = _block_rows(nb)
+        assert r == nb or (r == 256 and nb > 256)
 
 
 @pytest.mark.parametrize("nb", [1, 7, 97, 300, 510, 1000])
 def test_shard_codec_roundtrip_awkward_block_counts(nb):
-    """Regression for the degenerate grid: awkward nb used to collapse to
-    single-row blocks; now it must pick the largest divisor ≤ 256 AND stay
-    bit-identical to the reference through encode/decode."""
+    """Awkward nb (not a multiple of the block height) runs a ragged last
+    block, and must stay bit-identical to the reference through
+    encode/decode."""
     from repro.kernels.ref import shard_codec_ref, shard_decode_ref
     from repro.kernels.shard_codec import (
         shard_decode_kernel,
@@ -304,11 +308,11 @@ def test_shard_codec_roundtrip_awkward_block_counts(nb):
 
     rng = np.random.default_rng(nb)
     x = jnp.asarray(rng.normal(size=(nb, 256)).astype(np.float32))
-    c, s = shard_encode_kernel(x)
+    c, s = shard_encode_kernel(x, interpret=True)
     cr, sr = shard_codec_ref(x)
     assert np.array_equal(np.asarray(c), np.asarray(cr))
     assert np.array_equal(np.asarray(s), np.asarray(sr))
-    d = shard_decode_kernel(c, s)
+    d = shard_decode_kernel(c, s, interpret=True)
     dr = shard_decode_ref(cr, sr)
     assert np.array_equal(np.asarray(d), np.asarray(dr))
     # Round-trip error within the documented bound (fp32 slack included).
@@ -342,7 +346,7 @@ def test_quantize_pairs_bit_identical_with_kernel_encode():
         codes, scales, _ = int8_quantize(jnp.asarray(x))
         pad = (-x.size) % Q_BLOCK
         xf = np.pad(x.reshape(-1), (0, pad)).reshape(-1, Q_BLOCK)
-        kc, ks = shard_encode_kernel(jnp.asarray(xf))
+        kc, ks = shard_encode_kernel(jnp.asarray(xf), interpret=True)
         assert np.array_equal(np.asarray(kc), np.asarray(codes)), x.shape
         assert np.array_equal(np.asarray(ks), np.asarray(scales)), x.shape
 
@@ -416,6 +420,52 @@ def test_encode_state_int8_reduces_wire_and_bounds_error():
                     jax.tree_util.tree_leaves(decoded)):
         assert np.asarray(o).shape == np.asarray(d).shape
         assert np.asarray(o).dtype == np.asarray(d).dtype
+
+
+def test_int8_codes_round_the_exact_quotient():
+    """Codes are round-half-even of the exact ``x / scale``, whatever the f32
+    division returns. With block max 1.0, 0.94094485 / scale rounds onto
+    119.5 in f32 (plain rounding gives code 120) though the exact quotient
+    lies below it. Swept over values a few ulps around every half-code, of
+    both signs; the kernel and the references agree (the pairing tests),
+    the round trip holds ``scale/2`` within ROUNDTRIP_REL_SLACK, and a code
+    one off fails it."""
+    import jax.numpy as jnp
+    from repro.core.replication import (
+        decode_state,
+        encode_state,
+        roundtrip_max_error_ok,
+    )
+    from repro.optim.compression import int8_quantize
+
+    f32 = np.float32
+    scale = f32(1.0) * f32(1.0 / 127.0)
+    near = []
+    for k in range(127):
+        v = (f32(k) + f32(0.5)) * scale
+        for _ in range(4):
+            v = np.nextafter(v, f32(0))
+        for _ in range(9):
+            near += [v, -v]
+            v = np.nextafter(v, f32(2))
+    pad = (-len(near)) % 255
+    blocks = np.asarray(near + [f32(0)] * pad, f32).reshape(-1, 255)
+    x = np.concatenate([np.ones((len(blocks), 1), f32), blocks], axis=1)
+    x[0, 1] = f32(0.94094485)
+
+    codes, scales, _ = int8_quantize(jnp.asarray(x))
+    exact = np.round(x.astype(np.float64)
+                     / np.asarray(scales, np.float64)[:, None])
+    assert np.array_equal(np.asarray(codes), exact)
+    assert np.asarray(codes)[0, 1] == 119
+
+    tree = {"w": jnp.asarray(x)}
+    leaves, manifest, _ = encode_state(tree, "int8", verify_kernel=True)
+    decoded = decode_state(leaves, manifest, verify_kernel=True)
+    assert roundtrip_max_error_ok(tree, decoded, leaves)
+    off = np.asarray(decoded["w"]).copy()
+    off[0, 0] -= leaves[0].scales[0]
+    assert not roundtrip_max_error_ok(tree, {"w": off}, leaves)
 
 
 def test_encode_state_none_is_lossless_passthrough():

@@ -134,3 +134,26 @@ def test_elastic_loss_continuity_across_churn():
         print("OK continuity", arr[0], arr[-1], jumps.max())
     """)
     assert "OK continuity" in out
+
+
+def test_step_compile_timed_apart_from_step_time():
+    """The first step at a new (n, tp) compiles ahead of its call: the
+    compile lands in ``compile_seconds`` and the step times exclude it."""
+    import jax
+    from repro.configs import get_config
+    from repro.data.synthetic import TokenStream
+    from repro.elastic import ElasticTrainer
+    from repro.models import build_model
+
+    cfg = get_config("gpt2").reduced()
+    tr = ElasticTrainer(build_model(cfg), devices=jax.devices()[:1],
+                        initial=1, per_device_batch=2)
+    tr.init()
+    batch = {"tokens": TokenStream(vocab=cfg.vocab, seq_len=16,
+                                   seed=0).batch(range(2))}
+    tr.step(batch)
+    tr.step(batch)
+    assert list(tr.compile_seconds) == [(1, 1)]
+    assert tr.compile_seconds[(1, 1)] > 0
+    times = tr.metrics_snapshot()["step_times"][1]
+    assert len(times) == 2 and all(t > 0 for t in times)

@@ -1,5 +1,6 @@
 """Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles in
-kernels/ref.py, executed with interpret=True on CPU (task spec §c)."""
+kernels/ref.py, executed with interpret=True on CPU. Compiles for a TPU
+are checked in test_chip_compile.py."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -55,7 +56,7 @@ def test_flash_attention_vs_ref(case):
     spec = MaskSpec(kind, window=window, prefix_len=prefix)
     out = flash_attention_kernel(q, k, v, scale=scale, softcap=softcap,
                                  kind=kind, window=window, prefix_len=prefix,
-                                 block_q=64, block_k=64)
+                                 block_q=64, block_k=64, interpret=True)
     ref = R.attention_ref(q, k, v, spec, scale=scale, softcap=softcap,
                           is_local=True if window else None)
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -124,7 +125,8 @@ def test_wkv6_vs_ref(case):
     u = jax.random.normal(ks[4], (H, hd), jnp.float32) * 0.3
     state = jax.random.normal(jax.random.fold_in(KEY, 9), (B, H, hd, hd)) * 0.1
 
-    out, sf = wkv6_kernel(r, k, v, lw, u, state=state, chunk=chunk)
+    out, sf = wkv6_kernel(r, k, v, lw, u, state=state, chunk=chunk,
+                          interpret=True)
     ref_o, ref_s = R.wkv6_ref(r, k, v, lw, u, state=state)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref_o),
                                **_rec_tol(dtype))
@@ -170,7 +172,8 @@ def test_ssd_vs_ref(case):
     Cm = jax.random.normal(ks[4], (B, S, N), jnp.float32).astype(dtype)
     st = jax.random.normal(jax.random.fold_in(KEY, 11), (B, H, P, N)) * 0.1
 
-    y, hf = ssd_kernel(x, dt, A_log, Bm, Cm, state=st, chunk=chunk)
+    y, hf = ssd_kernel(x, dt, A_log, Bm, Cm, state=st, chunk=chunk,
+                       interpret=True)
     ry, rh = R.ssd_ref(x, dt, A_log, Bm, Cm, state=st)
     np.testing.assert_allclose(np.asarray(y), np.asarray(ry), **_rec_tol(dtype))
     np.testing.assert_allclose(np.asarray(hf), np.asarray(rh), **_rec_tol(dtype))
@@ -200,11 +203,11 @@ def test_ssd_chunked_xla_matches_ref():
 @pytest.mark.parametrize("nb", [1, 7, 64, 300])
 def test_shard_codec_roundtrip(nb):
     x = jax.random.normal(KEY, (nb, 256), jnp.float32) * 5.0
-    codes, scales = shard_encode_kernel(x)
+    codes, scales = shard_encode_kernel(x, interpret=True)
     rc, rs = R.shard_codec_ref(x)
     np.testing.assert_array_equal(np.asarray(codes), np.asarray(rc))
     np.testing.assert_allclose(np.asarray(scales), np.asarray(rs), rtol=1e-6)
-    back = shard_decode_kernel(codes, scales)
+    back = shard_decode_kernel(codes, scales, interpret=True)
     err = np.abs(np.asarray(back) - np.asarray(x))
     per_block_bound = np.asarray(scales)[:, None] * 0.5 + 1e-6
     assert (err <= per_block_bound).all()

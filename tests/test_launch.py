@@ -105,3 +105,37 @@ def test_hlo_analysis_dot_flops_parsing():
     ins = Instr("%d", "f32[8,32]", "dot",
                 "%a, %b), lhs_contracting_dims={1}, rhs_contracting_dims={0}")
     assert _dot_flops(ins, comp) == 2 * 8 * 32 * 64
+
+
+def test_device_peaks_keyed_by_device_kind():
+    from repro.launch.dryrun import (
+        DEVICE_PEAKS,
+        TARGET_DEVICE_KIND,
+        device_peaks,
+    )
+
+    assert device_peaks(TARGET_DEVICE_KIND)["flops"] == 197e12
+    assert TARGET_DEVICE_KIND in DEVICE_PEAKS
+    with pytest.raises(KeyError, match="no peaks for device kind"):
+        device_peaks("cpu")
+
+
+def test_compile_cache_dir_placed_from_outside(monkeypatch, tmp_path):
+    import jax
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.CACHE_ENV, str(tmp_path / "outside"))
+        assert compile_cache.enable_compile_cache(tmp_path / "default") == \
+            str(tmp_path / "outside")
+        assert jax.config.jax_compilation_cache_dir == before
+
+        monkeypatch.delenv(compile_cache.CACHE_ENV)
+        assert compile_cache.enable_compile_cache(tmp_path / "default") == \
+            str(tmp_path / "default")
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path /
+                                                           "default")
+        assert compile_cache.DEFAULT_CACHE_DIR == ROOT / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -559,7 +559,7 @@ def test_shard_report_counts_degraded_params():
                     "w2": S((3072, 768), np.float32)},
             "ln": S((768,), np.float32)}},
     }
-    mesh = AbstractMesh((("data", 4), ("model", 4)))
+    mesh = AbstractMesh((4, 4), ("data", "model"))
     rep = shard_report(mesh, params)
     assert rep["mesh_shape"] == {"data": 4, "model": 4}
     deg = rep["degraded"]
@@ -568,6 +568,6 @@ def test_shard_report_counts_degraded_params():
     assert deg["embed/model"]["bytes"] == 50257 * 768 * 4
     assert rep["replication_blowup"] > 1.0
     # tp=1 never degrades and never blows up
-    rep1 = shard_report(AbstractMesh((("data", 16), ("model", 1))), params)
+    rep1 = shard_report(AbstractMesh((16, 1), ("data", "model")), params)
     assert rep1["degraded"] == {}
     assert rep1["replication_blowup"] == pytest.approx(1.0)
