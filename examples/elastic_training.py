@@ -83,7 +83,10 @@ def main():
     print(f"[{len(trainer.active)} devices] step {trainer.step_count}: "
           f"loss {m['loss']:.4f}")
 
-    print("straggler report:", trainer.straggler_report())
+    for n, times in trainer.metrics_snapshot()["step_times"].items():
+        print(f"chaos.step.run on {n} devices: {len(times)} steps, mean "
+              f"{np.mean(times) * 1e3:.1f} ms, p95 "
+              f"{np.percentile(times, 95) * 1e3:.1f} ms")
     losses_ok = m["loss"] < 8.0
     print("ELASTIC_DEMO_OK" if losses_ok else "ELASTIC_DEMO_FAILED")
     return 0 if losses_ok else 1

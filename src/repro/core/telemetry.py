@@ -44,6 +44,14 @@ link was probabilistically detected or applied at the event boundary) — so
 the simulator and :class:`~repro.elastic.trainer.TrainerBackend` replays of
 one trace hash identically, the same way ``recovery.decision_digest`` does
 for decisions.
+
+**Real-clock recorder.** :class:`Recorder` is the one exception to the
+virtual clock: a live :class:`~repro.elastic.trainer.ElasticTrainer` keeps
+its own spans (``chaos.step``, ``chaos.compile``, ``chaos.move``,
+``chaos.handle`` and their children) on ``time.perf_counter_ns`` in a
+bounded ring, with counters in a :class:`MetricsRegistry`. Each span is
+also a ``jax.profiler.TraceAnnotation``, so a profiler capture shows it on
+the host plane beside the device's operations.
 """
 from __future__ import annotations
 
@@ -51,8 +59,11 @@ import hashlib
 import json
 import math
 import re
+import time
+from collections import deque
+from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Deque, Dict, Iterable, List, Optional, Tuple
 
 from repro.core.goodput import (
     CATEGORIES,
@@ -80,6 +91,8 @@ class Span:
     subject: Tuple
     attrs: Dict = field(default_factory=dict)
     children: List["Span"] = field(default_factory=list)
+    #: ``seq`` of the enclosing span, for a :class:`Recorder`'s rows
+    parent: Optional[int] = None
 
     @property
     def duration_s(self) -> float:
@@ -942,6 +955,66 @@ class MetricsRegistry:
 
 
 # ---------------------------------------------------------------------------
+# Real-clock recorder of a live trainer.
+# ---------------------------------------------------------------------------
+
+
+class Recorder:
+    """Spans and counters on the host's real clock.
+
+    A row is a :class:`Span` (``cat="host"``) with ``t0``/``t1`` in seconds
+    of ``time.perf_counter_ns`` (the clock of ``time.perf_counter``), its
+    ``seq`` in opening order and the ``seq`` of the span that was open
+    around it as ``parent``. Rows enter a ring of ``maxlen`` as they close,
+    so a child precedes its parent and memory stays fixed over a long run.
+    Every span is also a ``jax.profiler.TraceAnnotation`` of the same name,
+    inert unless a profiler session runs. Counters live in ``registry``."""
+
+    def __init__(self, maxlen: int = 1 << 14):
+        self.rows: Deque[Span] = deque(maxlen=maxlen)
+        self.registry = MetricsRegistry()
+        self._open: List[int] = []
+        self._seq = 0
+        self._annotation = None
+
+    @contextmanager
+    def span(self, name: str, **attrs):
+        """Times the body; yields the row, whose ``attrs`` the body may
+        extend."""
+        if self._annotation is None:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+        row = Span(name, "host", 0.0, 0.0, self._seq, (), attrs,
+                   parent=self._open[-1] if self._open else None)
+        self._seq += 1
+        self._open.append(row.seq)
+        with self._annotation(name):
+            row.t0 = time.perf_counter_ns() * 1e-9
+            try:
+                yield row
+            finally:
+                row.t1 = time.perf_counter_ns() * 1e-9
+                self._open.pop()
+                self.rows.append(row)
+
+    def count(self, name: str, amount: float = 1.0, help_text: str = "",
+              **labels):
+        """Adds ``amount`` to the counter ``name`` at ``labels``."""
+        self.registry.counter(name, help_text, tuple(labels)).inc(
+            amount, **labels)
+
+    def counter(self, name: str) -> Dict[Tuple[str, ...], float]:
+        """Label values -> total of a counter (empty before its first
+        ``count``)."""
+        fam = self.registry._families.get(name)
+        return dict(fam.samples) if fam is not None else {}
+
+    def named(self, name: str) -> List[Span]:
+        """The ring's rows of one name, oldest first."""
+        return [r for r in self.rows if r.name == name]
+
+
+# ---------------------------------------------------------------------------
 # Out-of-band collectors: snapshot reads of counters the layers keep anyway.
 # ---------------------------------------------------------------------------
 
@@ -1075,7 +1148,9 @@ def collect_goodput(reg: MetricsRegistry, report) -> None:
 
 
 def collect_trainer(reg: MetricsRegistry, trainer) -> None:
-    """Step-time histograms off an ``ElasticTrainer`` (wall seconds)."""
+    """Step-time histograms off an ``ElasticTrainer``'s recorder (the
+    ``chaos.step.run`` spans still in its ring) and the recorder's
+    counters."""
     snap = trainer.metrics_snapshot()
     hist = reg.histogram("chaos_trainer_step_seconds",
                          "Per-step wall time by active device count",
@@ -1087,6 +1162,10 @@ def collect_trainer(reg: MetricsRegistry, trainer) -> None:
               "Devices currently training").set(snap["n_active"])
     reg.counter("chaos_trainer_steps_total",
                 "Optimizer steps taken").inc(snap["step_count"])
+    for name, fam in sorted(trainer.tracer.registry._families.items()):
+        out = reg.counter(name, fam.help, fam.label_names)
+        for key, v in sorted(fam.samples.items()):
+            out.inc(v, **dict(zip(fam.label_names, key)))
 
 
 def collect_backend(reg: MetricsRegistry, backend, ledger, *,

@@ -22,9 +22,12 @@ synchronous data-parallel training over a device mesh that grows and shrinks
     land on a per-device link-override table layered over ``link_model``,
     so a degraded link reshapes the replication plans of later scale-outs
     exactly as it does in the simulator;
-  * straggler detection: per-step wall-time EWMA per cluster size flags
-    outliers to the monitor for scale-in recommendation (τ^sync-aware shard
-    planning already derates slow nodes during scale-out).
+  * one real-clock recorder (``tracer``, :class:`repro.core.telemetry.
+    Recorder`) holds every timing: ``chaos.step`` (``put_batch``, ``run``,
+    ``read_metrics``), ``chaos.compile``, ``chaos.move`` (``plan``,
+    ``codec``, ``transfer``, ``on_reshard``) and the backend's
+    ``chaos.handle``, with the counters ``chaos_compiles_total``,
+    ``chaos_compile_seconds_total`` and ``chaos_move_bytes_total``.
 
 It runs on whatever devices it is given: TPU chips (``chip_smoke.py``), or
 CPU devices under ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` for
@@ -32,8 +35,7 @@ a multi-device CPU demonstration (examples/elastic_training.py).
 """
 from __future__ import annotations
 
-import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
@@ -55,6 +57,7 @@ from repro.core.replication import (
     roundtrip_max_error_ok,
 )
 from repro.core.sharding_alg import NeighborLink
+from repro.core.telemetry import Recorder
 from repro.core.topology import MBPS
 
 #: per-byte transmission delay standing in for a severed link: the Alg-1/2
@@ -68,8 +71,39 @@ class ScaleEvent:
     kind: str
     device: str
     step: int
+    #: seconds of the move's plan, codec and transfer spans (the data
+    #: pipeline's ``on_reshard`` callback left out)
     wall_s: float
     plan_summary: Optional[dict] = None
+
+
+def _box(index, shape) -> List[tuple]:
+    return [sl.indices(dim)[:2] for sl, dim in zip(index, shape)]
+
+
+def received_bytes(state, target) -> int:
+    """Bytes the devices of ``target`` (a sharding, or a tree of them like
+    ``state``) must receive to hold ``state`` there: per leaf and device, the
+    bytes of the block it holds afterwards less its overlap with the block
+    that device held before. From the shardings' index maps alone; a leaf
+    without a sharding (a host array) counts whole."""
+    leaves = jax.tree.leaves(state)
+    targets = ([target] * len(leaves) if isinstance(target, jax.sharding.Sharding)
+               else jax.tree.leaves(target))
+    total = 0
+    for leaf, new in zip(leaves, targets):
+        shape = leaf.shape
+        old = getattr(leaf, "sharding", None)
+        held = old.devices_indices_map(shape) if old is not None else {}
+        for dev, index in new.devices_indices_map(shape).items():
+            block = _box(index, shape)
+            want = int(np.prod([b - a for a, b in block]))
+            if dev in held:
+                have = [(max(a, c), min(b, d)) for (a, b), (c, d)
+                        in zip(block, _box(held[dev], shape))]
+                want -= int(np.prod([max(0, b - a) for a, b in have]))
+            total += want * leaf.dtype.itemsize
+    return total
 
 
 class ElasticTrainer:
@@ -98,8 +132,10 @@ class ElasticTrainer:
         # don't clobber each other; the slowest surviving impairment wins.
         self._link_overrides: Dict[int, Dict[object, NeighborLink]] = {}
         self._step_fns: Dict[tuple, Callable] = {}
-        #: seconds spent compiling the train step, per (n, tp)
-        self.compile_seconds: Dict[tuple, float] = {}
+        #: the executable behind each ``_step_fns`` entry, for its HLO text
+        self._compiled: Dict[tuple, object] = {}
+        #: every timing of this trainer: spans on the host's real clock
+        self.tracer = Recorder()
         # Current parallelism layout: tp-ways of tensor parallelism over the
         # active devices (1 = the pure-DP layout every pre-reshard trainer
         # ran — meshes, shardings and compiled steps are then bit-identical
@@ -108,7 +144,6 @@ class ElasticTrainer:
         self._microbatch = 1
         self.step_count = 0
         self.events: List[ScaleEvent] = []
-        self._step_times: Dict[int, list] = {}
         self.state = None
         self._seed = seed
         # Recovery tiers (attach_recovery): the in-memory neighbor-replica
@@ -269,10 +304,16 @@ class ElasticTrainer:
             self.on_reshard(self.device_ids())
         return self.state
 
+    @property
+    def compile_seconds(self) -> Dict[tuple, float]:
+        """Seconds spent compiling the train step, per (n, tp)."""
+        return {(int(n), int(tp)): s for (n, tp), s in
+                self.tracer.counter("chaos_compile_seconds_total").items()}
+
     def _get_step_fn(self, n: int, batch):
         """The jitted step for ``(n, tp)``, compiled ahead of its first call
-        (the call then reuses that executable) so that the compile is timed
-        apart from the step."""
+        (the call then reuses that executable) inside a ``chaos.compile``
+        span, so that the compile is timed apart from the step."""
         key = (n, self._tp)
         if key not in self._step_fns:
             step = self.model.make_train_step()
@@ -282,27 +323,72 @@ class ElasticTrainer:
                 in_shardings=(state_sh, self._batch_sharding()),
                 out_shardings=(state_sh, None),
             )
-            t0 = time.perf_counter()
-            fn.lower(self.state, batch).compile()
-            self.compile_seconds[key] = time.perf_counter() - t0
+            with self.tracer.span("chaos.compile", n=n, tp=self._tp) as sp:
+                self._compiled[key] = fn.lower(self.state, batch).compile()
+            self.tracer.count("chaos_compiles_total",
+                              help_text="Train-step compiles by layout",
+                              n=n, tp=self._tp)
+            self.tracer.count("chaos_compile_seconds_total", sp.duration_s,
+                              help_text="Seconds compiling the train step",
+                              n=n, tp=self._tp)
             self._step_fns[key] = fn
         return self._step_fns[key]
 
     def step(self, batch: dict):
         """batch arrays lead with global_batch (= per_device × n_active).
-        The recorded step time runs from dispatch until the new state is
-        ready on the devices; it excludes the compile."""
+        One ``chaos.step`` span: ``put_batch`` (the batch onto the mesh),
+        ``run`` (dispatch until the new state is ready on the devices; the
+        step time, without the compile) and ``read_metrics``."""
         n = len(self.active)
-        batch = jax.device_put(batch, self._batch_sharding())
-        fn = self._get_step_fn(n, batch)
-        t0 = time.perf_counter()
-        self.state, metrics = fn(self.state, batch)
-        jax.block_until_ready(self.state)
-        dt = time.perf_counter() - t0
-        metrics = jax.tree.map(float, metrics)
-        self._step_times.setdefault(n, []).append(dt)
+        rows = int(jax.tree.leaves(batch)[0].shape[0])
+        tr = self.tracer
+        with tr.span("chaos.step", n=n, tp=self._tp, rows=rows):
+            with tr.span("chaos.step.put_batch"):
+                batch = jax.device_put(batch, self._batch_sharding())
+            fn = self._get_step_fn(n, batch)
+            with tr.span("chaos.step.run"):
+                self.state, metrics = fn(self.state, batch)
+                jax.block_until_ready(self.state)
+            with tr.span("chaos.step.read_metrics"):
+                metrics = jax.tree.map(float, metrics)
         self.step_count += 1
         return metrics
+
+    # -- state movement ---------------------------------------------------------------
+
+    def _move(self, kind: str, target, prepare, codec=None):
+        """Moves the state onto a new layout in one ``chaos.move`` span:
+        ``codec`` (the ``codec`` callable, when given), ``plan``
+        (``prepare``, which switches the trainer to the new layout, then
+        ``target()``, its sharding or tree of them, and the bytes the
+        devices receive), ``transfer`` (``device_put`` until ready),
+        ``on_reshard``. Returns (``prepare``'s result, the codec's, the
+        bytes, codec + plan + transfer seconds)."""
+        tr = self.tracer
+        with tr.span("chaos.move", kind=kind,
+                     **{"from": (len(self.active), self._tp)}) as mv:
+            spans, coded = [], None
+            if codec is not None:
+                with tr.span("chaos.move.codec") as sp:
+                    coded = codec()
+                spans.append(sp)
+            with tr.span("chaos.move.plan") as sp:
+                prepared = prepare()
+                sharding = target()
+                moved = received_bytes(self.state, sharding)
+            spans.append(sp)
+            with tr.span("chaos.move.transfer") as sp:
+                self.state = jax.device_put(self.state, sharding)
+                jax.block_until_ready(self.state)
+            spans.append(sp)
+            mv.attrs.update(to=(len(self.active), self._tp), bytes=moved)
+            if self.on_reshard:
+                with tr.span("chaos.move.on_reshard"):
+                    self.on_reshard(self.device_ids())
+        tr.count("chaos_move_bytes_total", moved,
+                 help_text="Bytes devices received on layout changes",
+                 kind=kind)
+        return prepared, coded, moved, sum(x.duration_s for x in spans)
 
     # -- elasticity -----------------------------------------------------------------
 
@@ -321,41 +407,42 @@ class ElasticTrainer:
             if not candidates:
                 raise RuntimeError("device pool exhausted")
             device = candidates[0]
-        t0 = time.perf_counter()
-        # Chaos plan over current members as neighbors of the joining device,
-        # through their effective (possibly degraded/severed) links.
-        neighbors = self.replication_neighbors()
-        plan = plan_replication(self.state, neighbors)
-        codec_summary = None
-        if eff_codec != wire_codec.CODEC_NONE:
+
+        def prepare():
+            # Chaos plan over current members as neighbors of the joining
+            # device, through their effective (possibly degraded/severed)
+            # links. Membership change resets the layout to the
+            # replicate-only baseline (tp = 1); a reshard policy re-applies
+            # tensor parallelism via apply_reshard.
+            plan = plan_replication(self.state, self.replication_neighbors())
+            self._tp = 1
+            self._microbatch = 1
+            self.active = self.active + [device]
+            return plan
+
+        def run_codec():
             enc, manifest, wire = encode_state(self.state, eff_codec,
                                                verify_kernel=True)
             decoded = decode_state(enc, manifest, verify_kernel=True)
             assert roundtrip_max_error_ok(self.state, decoded, enc), \
                 "shard codec round-trip exceeded the scale/2 error bound"
-            codec_summary = {
+            return {
                 "codec": eff_codec,
                 "payload_bytes": int(manifest.total_bytes),
                 "wire_bytes": int(wire),
                 "wire_reduction": (float(manifest.total_bytes) / wire
                                    if wire else 1.0),
             }
-        # Physical state movement onto the enlarged mesh. Membership change
-        # resets the layout to the replicate-only baseline (tp = 1); a
-        # reshard policy re-applies tensor parallelism via apply_reshard.
-        self._tp = 1
-        self._microbatch = 1
-        self.active = self.active + [device]
-        self.state = jax.device_put(self.state, self._state_sharding())
-        jax.block_until_ready(self.state)
-        wall = time.perf_counter() - t0
-        if self.on_reshard:
-            self.on_reshard(self.device_ids())
+
+        plan, codec_summary, moved, wall = self._move(
+            "scale-out", self._state_sharding, prepare,
+            run_codec if eff_codec != wire_codec.CODEC_NONE else None)
         summary = {
             "shard_size": plan.assignment.shard_size,
             "n_shards": plan.assignment.n_shards,
             "bytes_per_source": plan.bytes_per_source,
             "predicted_completion_s": plan.assignment.completion_s,
+            "bytes_moved": moved,
         }
         if codec_summary is not None:
             summary["codec"] = codec_summary
@@ -371,22 +458,21 @@ class ElasticTrainer:
             device = self.active[-1]
         if len(self.active) <= 1:
             raise RuntimeError("cannot scale below one device")
-        t0 = time.perf_counter()
         # Snapshot state on survivors BEFORE dropping the device. The
-        # device_put below gathers any tp-sharded leaves back to full
-        # replicas on the survivor mesh (the replicate-only baseline); a
-        # reshard policy re-applies tensor parallelism afterwards.
+        # device_put gathers any tp-sharded leaves back to full replicas on
+        # the survivor mesh (the replicate-only baseline); a reshard policy
+        # re-applies tensor parallelism afterwards.
         survivors = [d for d in self.active if d != device]
-        self._tp = 1
-        self._microbatch = 1
-        self.active = survivors
-        self.state = jax.device_put(self.state, self._state_sharding())
-        jax.block_until_ready(self.state)
-        wall = time.perf_counter() - t0
-        if self.on_reshard:
-            self.on_reshard(self.device_ids())
-        ev = ScaleEvent("node-failure" if failure else "scale-in",
-                        str(device), self.step_count, wall)
+        kind = "node-failure" if failure else "scale-in"
+
+        def prepare():
+            self._tp = 1
+            self._microbatch = 1
+            self.active = survivors
+
+        _, _, moved, wall = self._move(kind, self._state_sharding, prepare)
+        ev = ScaleEvent(kind, str(device), self.step_count, wall,
+                        {"bytes_moved": moved})
         self.events.append(ev)
         return ev
 
@@ -402,18 +488,18 @@ class ElasticTrainer:
         n = len(self.active)
         if tp < 1 or n % tp:
             raise ValueError(f"tp={tp} does not divide {n} active devices")
-        t0 = time.perf_counter()
-        self._tp = tp
-        self._microbatch = max(1, int(microbatch))
-        self.state = jax.device_put(self.state, self._state_shardings())
-        jax.block_until_ready(self.state)
-        wall = time.perf_counter() - t0
+
+        def prepare():
+            self._tp = tp
+            self._microbatch = max(1, int(microbatch))
+
+        _, _, moved, wall = self._move("reshard", self._state_shardings,
+                                       prepare)
         ev = ScaleEvent("reshard", str(self.active[0]), self.step_count,
                         wall, {"shape": [n // tp, tp],
-                               "microbatch": self._microbatch})
+                               "microbatch": self._microbatch,
+                               "bytes_moved": moved})
         self.events.append(ev)
-        if self.on_reshard:
-            self.on_reshard(self.device_ids())
         return ev
 
     # -- recovery tiers (repro.checkpoint wired into the live trainer) ---------
@@ -500,27 +586,20 @@ class ElasticTrainer:
 
     def metrics_snapshot(self) -> dict:
         """Point-in-time read of training observables for telemetry scrapes
-        (repro.core.telemetry). Pure read; wall-clock step times stay raw —
-        histogram bucketing is the registry's job."""
+        (repro.core.telemetry). Pure read; step times are the
+        ``chaos.step.run`` spans still in the recorder's ring, raw, by the
+        active device count of their ``chaos.step`` — histogram bucketing is
+        the registry's job."""
+        steps = {r.seq: r for r in self.tracer.named("chaos.step")}
+        times: Dict[int, list] = {}
+        for r in self.tracer.named("chaos.step.run"):
+            times.setdefault(steps[r.parent].attrs["n"], []).append(
+                r.duration_s)
         return {
             "n_active": len(self.active),
             "step_count": self.step_count,
-            "step_times": {n: list(ts) for n, ts in
-                           sorted(self._step_times.items())},
+            "step_times": dict(sorted(times.items())),
         }
-
-    # -- stragglers ------------------------------------------------------------------
-
-    def straggler_report(self, threshold: float = 2.0) -> dict:
-        """Step-time statistics; a production deployment feeds per-node
-        compute times here — on host-simulated devices we report the global
-        step-time EWMA per cluster size (the control-plane hook)."""
-        out = {}
-        for n, times in self._step_times.items():
-            arr = np.asarray(times[1:] or times)  # drop the warm-up step
-            out[n] = {"mean_s": float(arr.mean()), "p95_s": float(np.percentile(arr, 95)),
-                      "n_steps": len(arr)}
-        return out
 
 
 # ---------------------------------------------------------------------------
@@ -562,6 +641,8 @@ class TrainerBackend:
                  tensor_sizes: Optional[Sequence[int]] = None,
                  policy="fixed"):
         self.trainer = trainer
+        #: the trainer's recorder (a trainer double without one gets its own)
+        self.tracer = getattr(trainer, "tracer", None) or Recorder()
         self.batch_fn = batch_fn
         self.steps_between = steps_between
         self.min_active = min_active
@@ -621,6 +702,12 @@ class TrainerBackend:
         return min(tr.active, key=lambda d: d.id) if tr.active else None
 
     def handle(self, seq: int, ev: ChurnEvent, ledger: EventLedger):
+        """One event in a ``chaos.handle`` span: the engine's backend and
+        the recovery policy, with any state moves as child spans."""
+        with self.tracer.span("chaos.handle", kind=ev.kind, seq=seq):
+            self._handle(seq, ev, ledger)
+
+    def _handle(self, seq: int, ev: ChurnEvent, ledger: EventLedger):
         tr = self.trainer
         if ev.kind == "scheduler-fault":
             # Coordinator swap, trainer-side: no virtual clock to elect on,

@@ -286,10 +286,13 @@ def attention_sublayer(
         else:
             q_offset = 0
 
-    o = blocked_attention(
-        q, k, v, spec, scale=scale, softcap=cfg.attn_softcap,
-        q_offset=q_offset, is_local=is_local, use_pallas=use_pallas,
-    )
+    # The scope names every device op of the core (scores, softmax, the
+    # products with V), XLA's or the Pallas kernel's, forward and backward.
+    with jax.named_scope("attention_core"):
+        o = blocked_attention(
+            q, k, v, spec, scale=scale, softcap=cfg.attn_softcap,
+            q_offset=q_offset, is_local=is_local, use_pallas=use_pallas,
+        )
     o = constrain(o, "batch", None, "model", None)
     out = o.reshape(B, S, H * hd) @ params["wo"].astype(dt)
     return constrain(out, "batch", None, None), new_cache

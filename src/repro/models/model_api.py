@@ -94,10 +94,11 @@ class Model:
                                     use_pallas=use_pallas)
 
             (tot, metrics), grads = jax.value_and_grad(lf, has_aux=True)(params)
-            grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip)
-            updates, new_opt = opt.update(grads, opt_state, params)
-            new_params = jax.tree.map(lambda p, u: p - u.astype(p.dtype),
-                                      params, updates)
+            with jax.named_scope("optimizer"):
+                grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip)
+                updates, new_opt = opt.update(grads, opt_state, params)
+                new_params = jax.tree.map(lambda p, u: p - u.astype(p.dtype),
+                                          params, updates)
             metrics = dict(metrics, grad_norm=gnorm, total_loss=tot)
             return {"params": new_params, "opt": new_opt}, metrics
 
