@@ -16,8 +16,10 @@ matches the jnp reference bit for bit on the whole training state.
 Four chips (``--chips 4``): the trainer starts on 2 chips and replays a join
 under the int8 codec, a join to 4 chips that reshards to (dp, tp) = (2, 2),
 a node failure and a checkpoint. Every scale-out, scale-in and reshard must
-leave the state bit-identical, and the first step on the 4-chip mesh must
-give the loss of the same global batch stepped on one chip.
+leave the state bit-identical, every layout's step must run attention's
+core on the path the backend selects (the Pallas kernel, per shard, on a
+TPU), and the first step on the 4-chip mesh must give the loss of the same
+global batch stepped on one chip (on XLA's attention).
 
 Every check prints one ``CHECK`` line. The last line of standard output is
 ``{"ok": true, "device": {...}}`` when every check passed; a failed check or
@@ -278,8 +280,9 @@ def _print_model(cfg, params, seq, batch):
 
 
 def _print_steps(tr, seq, kind):
-    for key, s in sorted(tr.compile_seconds.items()):
-        print(f"compile seconds (n, tp)={key}: {s:.2f}")
+    for sp in tr.tracer.named("chaos.compile"):
+        print(f"compile seconds (n, tp)=({sp.attrs['n']}, {sp.attrs['tp']}): "
+              f"{sp.duration_s:.2f}; attention {sp.attrs['attention']}")
     for n, times in sorted(tr.metrics_snapshot()["step_times"].items()):
         steady = times[1:] or times
         med = statistics.median(steady)
@@ -417,6 +420,7 @@ def phase_four_chips(args, checks):
 
     from repro.checkpoint import MemoryReplicaStore
     from repro.data.synthetic import TokenStream
+    from repro.elastic.trainer import kernels_selected
 
     cfg, model, seq = _model(args)
     pool = jax.devices()[:4]
@@ -476,6 +480,10 @@ def phase_four_chips(args, checks):
            and codec_moves[0]["codec"]["codec"] == "int8")
 
     _print_steps(tr, seq, device_label(pool[0]))
+    want = "pallas" if kernels_selected() else "xla"
+    checks("attention-path", all(sp.attrs["attention"] == want for sp in
+                                 tr.tracer.named("chaos.compile")),
+           f"every layout's step runs attention on {want}")
     print(f"losses (n, tp, loss): "
           f"{[(n, tp, round(l, 4)) for n, tp, l in tr.losses]}")
     checks("losses-finite", bool(np.isfinite([l for *_, l in tr.losses])

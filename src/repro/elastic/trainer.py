@@ -27,7 +27,11 @@ synchronous data-parallel training over a device mesh that grows and shrinks
     ``read_metrics``), ``chaos.compile``, ``chaos.move`` (``plan``,
     ``codec``, ``transfer``, ``on_reshard``) and the backend's
     ``chaos.handle``, with the counters ``chaos_compiles_total``,
-    ``chaos_compile_seconds_total`` and ``chaos_move_bytes_total``.
+    ``chaos_compile_seconds_total``, ``chaos_attention_sites_total`` and
+    ``chaos_move_bytes_total``;
+  * on a TPU the step runs attention's core in the Pallas flash-attention
+    kernel, forward and backward (``kernels/ops.flash_attention``), per
+    shard on a mesh of several devices; elsewhere it takes the XLA path.
 
 It runs on whatever devices it is given: TPU chips (``chip_smoke.py``), or
 CPU devices under ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` for
@@ -59,6 +63,7 @@ from repro.core.replication import (
 from repro.core.sharding_alg import NeighborLink
 from repro.core.telemetry import Recorder
 from repro.core.topology import MBPS
+from repro.models.layers import attention_sites
 
 #: per-byte transmission delay standing in for a severed link: the Alg-1/2
 #: planner derates such a neighbor to (near) zero shards, so it drops out of
@@ -104,6 +109,16 @@ def received_bytes(state, target) -> int:
                 want -= int(np.prod([max(0, b - a) for a, b in have]))
             total += want * leaf.dtype.itemsize
     return total
+
+
+def kernels_selected() -> bool:
+    """Whether train steps run attention's core in the Pallas kernel: where
+    JAX's default backend is a TPU (elsewhere the kernels would run in the
+    interpreter). It selects attention's kernel alone: the recurrences of
+    RWKV-6, Mamba-2 and Zamba2 (``kernels/ops.wkv6``, ``ops.ssd``) train on
+    their XLA path on every backend, since their kernels have no per-shard
+    path and no v5e compile test."""
+    return jax.default_backend() == "tpu"
 
 
 class ElasticTrainer:
@@ -310,21 +325,49 @@ class ElasticTrainer:
         return {(int(n), int(tp)): s for (n, tp), s in
                 self.tracer.counter("chaos_compile_seconds_total").items()}
 
+    def _abstract_mesh(self):
+        """The context of a step's trace and of each of its calls: the
+        layout's abstract mesh, which the attention op reads to run its
+        kernel per shard. It names no devices, so a layout's step serves
+        every later set of n devices (a concrete mesh would have to match
+        the devices the step was compiled for)."""
+        return jax.sharding.use_abstract_mesh(self.mesh().abstract_mesh)
+
     def _get_step_fn(self, n: int, batch):
         """The jitted step for ``(n, tp)``, compiled ahead of its first call
         (the call then reuses that executable) inside a ``chaos.compile``
-        span, so that the compile is timed apart from the step."""
+        span, so that the compile is timed apart from the step. The step
+        runs attention's core in the Pallas kernel where the backend is a
+        TPU; the span's ``attention`` attribute and the counter
+        ``chaos_attention_sites_total`` say which implementation the traced
+        attention sites took. The layout's abstract mesh is set while
+        tracing (and in ``step``), so the kernel runs per shard on a mesh of
+        several devices."""
         key = (n, self._tp)
         if key not in self._step_fns:
-            step = self.model.make_train_step()
+            # Asked only where selected: a model without a kernel path keeps
+            # the argument-free make_train_step() elsewhere.
+            kw = {"use_pallas": "attention"} if kernels_selected() else {}
+            step = self.model.make_train_step(**kw)
             state_sh = self._state_shardings()
             fn = jax.jit(
                 step,
                 in_shardings=(state_sh, self._batch_sharding()),
                 out_shardings=(state_sh, None),
             )
-            with self.tracer.span("chaos.compile", n=n, tp=self._tp) as sp:
+            with self.tracer.span("chaos.compile", n=n, tp=self._tp) as sp, \
+                    attention_sites() as sites, self._abstract_mesh():
                 self._compiled[key] = fn.lower(self.state, batch).compile()
+                impls = sorted(set(sites))
+                sp.attrs["attention"] = (
+                    impls[0] if len(impls) == 1 else
+                    "mixed" if impls else "none")
+            for impl in impls:
+                self.tracer.count("chaos_attention_sites_total",
+                                  sites.count(impl),
+                                  help_text="Traced attention sites by "
+                                            "implementation and layout",
+                                  impl=impl, n=n, tp=self._tp)
             self.tracer.count("chaos_compiles_total",
                               help_text="Train-step compiles by layout",
                               n=n, tp=self._tp)
@@ -346,7 +389,7 @@ class ElasticTrainer:
             with tr.span("chaos.step.put_batch"):
                 batch = jax.device_put(batch, self._batch_sharding())
             fn = self._get_step_fn(n, batch)
-            with tr.span("chaos.step.run"):
+            with tr.span("chaos.step.run"), self._abstract_mesh():
                 self.state, metrics = fn(self.state, batch)
                 jax.block_until_ready(self.state)
             with tr.span("chaos.step.read_metrics"):

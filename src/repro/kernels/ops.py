@@ -9,24 +9,28 @@ modules have no ``interpret`` default, so every caller on the training path
 comes through here. ``use_pallas=True`` paths in the models route here too.
 
 Autodiff: ``pallas_call`` with carried VMEM scratch has no JVP rule, so each
-kernel is wrapped in ``jax.custom_vjp`` whose backward differentiates the
-mathematically-identical XLA path (models/layers.blocked_attention,
-models/rwkv6.wkv6_chunked, models/mamba2.ssd_chunked) — forward speed from
-the kernel, exact gradients from XLA. A fused backward kernel is the
-next step for TPU performance work.
+kernel is wrapped in ``jax.custom_vjp``. Flash attention's backward is its
+own pair of kernels (dK/dV, then dQ, from the forward's output and row
+logsumexp), so attention trains with no S×S tensor in HBM in either
+direction. WKV6 and SSD keep a forward kernel whose backward differentiates
+the mathematically identical XLA path (models/rwkv6.wkv6_chunked,
+models/mamba2.ssd_chunked).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import shard_codec as _codec
 from repro.kernels import ssd as _ssd
 from repro.kernels import wkv6 as _wkv6
-from repro.models.layers import MaskSpec
+from repro.models.layers import MaskSpec, blocked_attention, note_attention_site
 
 
 def _interpret() -> bool:
@@ -38,50 +42,103 @@ def _interpret() -> bool:
 # ---------------------------------------------------------------------------
 
 
+@dataclasses.dataclass(frozen=True)
+class _FaArgs:
+    """The static half of one attention call."""
+
+    scale: float
+    softcap: float
+    kind: str
+    window: int
+    prefix_len: int
+    q_offset: int
+    blocks: tuple  # ((bq, bk) of the forward, the dK/dV and the dQ kernel)
+
+    def kw(self):
+        return dict(scale=self.scale, softcap=self.softcap, kind=self.kind,
+                    window=self.window, prefix_len=self.prefix_len,
+                    q_offset=self.q_offset, interpret=_interpret())
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _fa_op(q, k, v, static):
-    spec, scale, softcap, q_offset = static
-    window = spec.window
-    return _fa.flash_attention_kernel(
-        q, k, v, scale=scale, softcap=softcap, kind=spec.kind, window=window,
-        prefix_len=spec.prefix_len, q_offset=q_offset, interpret=_interpret())
+def _fa_op(q, k, v, a: _FaArgs):
+    return _fa_fwd(q, k, v, a)[0]
 
 
-def _fa_fwd(q, k, v, static):
-    return _fa_op(q, k, v, static), (q, k, v)
+def _fa_fwd(q, k, v, a: _FaArgs):
+    (bq, bk), _, _ = a.blocks
+    o, lse = _fa.flash_attention_fwd(q, k, v, block_q=bq, block_k=bk,
+                                     **a.kw())
+    return o, (q, k, v, o, lse)
 
 
-def _fa_bwd(static, res, g):
-    from repro.models.layers import blocked_attention
-
-    spec, scale, softcap, q_offset = static
-    q, k, v = res
-
-    def xla(q, k, v):
-        return blocked_attention(q, k, v, spec, scale=scale, softcap=softcap,
-                                 q_offset=q_offset,
-                                 is_local=True if spec.window else None,
-                                 use_pallas=False)
-
-    _, vjp = jax.vjp(xla, q, k, v)
-    return vjp(g)
+def _fa_bwd(a: _FaArgs, res, do):
+    _, dkv, dq = a.blocks
+    return _fa.flash_attention_bwd(*res, do, blocks_dkv=dkv, blocks_dq=dq,
+                                   **a.kw())
 
 
 _fa_op.defvjp(_fa_fwd, _fa_bwd)
 
 
+def _per_shard(q, k):
+    """How the kernel meets the traced program's mesh: ``None`` on one
+    device (or inside a ``shard_map`` already), ``(mesh, spec)`` to map it
+    per shard, batch over every axis but ``model`` and heads over
+    ``model``, or ``False`` where the batch or the heads do not divide."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or mesh.are_all_axes_manual:
+        return None
+    if mesh.manual_axes:
+        return False
+    heads = "model" if "model" in mesh.axis_names else None
+    batch = tuple(a for a in mesh.axis_names if a != heads)
+    nb = math.prod(mesh.shape[a] for a in batch)
+    nm = mesh.shape[heads] if heads else 1
+    if q.shape[0] % nb or q.shape[2] % nm or k.shape[2] % nm:
+        return False
+    return mesh, P(batch, None, heads, None)
+
+
 def flash_attention(q, k, v, spec: MaskSpec, *, scale, softcap=0.0,
-                    q_offset=0, is_local=None, block_q=128, block_k=128):
+                    q_offset=0, is_local=None):
     """Contract-compatible with models.layers.blocked_attention.
 
-    ``is_local`` must be static here (None/True/False): a traced per-layer
-    flag (gemma2 inside lax.scan) stays on the XLA path — see DESIGN.md §6.
+    Runs the Pallas kernel, forward and backward, where its contract holds
+    for the input: a static ``is_local`` (None/True/False) and
+    ``q_offset``, lengths that divide into blocks of at least 128
+    (``flash_attention.choose_blocks``, which also picks the block sizes),
+    and a batch and heads that divide the traced program's mesh. Any other
+    input, Gemma2's traced per-layer ``is_local`` among them, takes the XLA
+    path for forward and backward alike.
+
+    Where the traced program's mesh (``jax.set_mesh``, or the abstract mesh
+    of ``jax.sharding.use_abstract_mesh`` as the trainer sets it) spans more
+    than one device, the kernel runs per shard under ``jax.shard_map``, so
+    the ``pallas_call`` never sees a global array.
     """
-    if is_local is not None and not isinstance(is_local, bool):
-        raise ValueError("pallas path needs a static is_local; use the XLA path")
-    if is_local is False:
-        spec = MaskSpec(spec.kind, window=0, prefix_len=spec.prefix_len)
-    return _fa_op(q, k, v, (spec, float(scale), float(softcap), int(q_offset)))
+    blocks = _fa.choose_blocks(q.shape[1], k.shape[1])
+    shard = _per_shard(q, k)
+    if (blocks is None or shard is False or not isinstance(q_offset, int)
+            or not (is_local is None or isinstance(is_local, bool))):
+        return blocked_attention(q, k, v, spec, scale=scale, softcap=softcap,
+                                 q_offset=q_offset, is_local=is_local)
+    note_attention_site("pallas")
+    a = _FaArgs(float(scale), float(softcap), spec.kind,
+                0 if is_local is False else spec.window, spec.prefix_len,
+                q_offset, tuple(blocks[n] for n in ("fwd", "dkv", "dq")))
+
+    def call(q, k, v):
+        return _fa_op(q, k, v, a)
+
+    if shard is not None:
+        mesh, pspec = shard
+        # Every operand and the output are split over every mesh axis, so
+        # no value is replicated across shards and the vma check has
+        # nothing to add (it would need each pallas_call output typed).
+        call = jax.shard_map(call, mesh=mesh, in_specs=(pspec,) * 3,
+                             out_specs=pspec, check_vma=False)
+    return call(q, k, v)
 
 
 # ---------------------------------------------------------------------------

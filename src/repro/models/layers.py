@@ -4,14 +4,23 @@ All layers are pure functions over param dicts so they compose with
 ``jax.lax.scan`` over stacked layer parameters and with GSPMD sharding rules
 keyed on parameter paths (see ``repro/models/sharding.py``).
 
-The attention here is the **XLA path**: an online-softmax scan over KV blocks
-(O(Sq·Bk) live memory, never materializing the S×S score matrix) so that 32k
-prefill compiles with bounded temps. The Pallas TPU kernel in
-``repro/kernels/flash_attention.py`` implements the same contract for the
-hot path on real hardware; both are checked against ``kernels/ref.py``.
+Attention has two implementations of one contract, both checked against
+``kernels/ref.py``. ``blocked_attention`` with ``use_pallas`` set (``True``,
+or ``"attention"`` as the trainer asks on a TPU) runs the Pallas
+flash-attention kernel (``repro/kernels/flash_attention.py``, through
+``kernels/ops.flash_attention``) forward and backward wherever its contract
+holds for the input. Every other call,
+and every input outside the kernel's contract (decode, lengths that do not
+divide into blocks, Gemma2's traced per-layer window), takes the **XLA
+path** here: a direct softmax for KV up to 8192 (S×S scores under per-layer
+remat) and an online-softmax scan over KV blocks beyond (O(Sq·Bk) live
+memory, so 32k prefill compiles with bounded temps). ``attention_sites``
+records which path each traced call took.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 import math
 from functools import partial
@@ -115,6 +124,30 @@ def _mask_block(spec: MaskSpec, q_pos, kv_pos, is_local=None):
 # Blocked flash-style attention (XLA path).
 # ---------------------------------------------------------------------------
 
+#: the list that ``attention_sites()`` collects into, set for its body
+#: alone (and only in its own thread or task)
+_sites: contextvars.ContextVar = contextvars.ContextVar("attention_sites",
+                                                        default=None)
+
+
+@contextlib.contextmanager
+def attention_sites():
+    """Yields a list that collects, in trace order, the implementation
+    (``"pallas"`` or ``"xla"``) of every attention core traced in the
+    body; outside such a body nothing is kept."""
+    sites: list = []
+    token = _sites.set(sites)
+    try:
+        yield sites
+    finally:
+        _sites.reset(token)
+
+
+def note_attention_site(impl: str):
+    sites = _sites.get()
+    if sites is not None:
+        sites.append(impl)
+
 
 def blocked_attention(
     q,
@@ -133,7 +166,8 @@ def blocked_attention(
 
     q: (B, Sq, H, hd); k, v: (B, Skv, K, hd) with H % K == 0 (GQA).
     ``q_offset``: absolute position of q[0] (decode: cache write position).
-    Returns (B, Sq, H, hd) in q.dtype.
+    Returns (B, Sq, H, hd) in q.dtype. ``use_pallas``: the Pallas kernel
+    where its contract holds for the input, else this XLA path.
     """
     if use_pallas:
         from repro.kernels import ops as kernel_ops
@@ -142,6 +176,7 @@ def blocked_attention(
             q, k, v, spec, scale=scale, softcap=softcap, q_offset=q_offset,
             is_local=is_local,
         )
+    note_attention_site("xla")
 
     B, Sq, H, hd = q.shape
     Skv, K = k.shape[1], k.shape[2]

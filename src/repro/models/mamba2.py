@@ -130,7 +130,9 @@ def block_apply(p, x, cfg, state=None, use_pallas=False):
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])  # (B,S,H)
     xh = xs.reshape(B, S, H, P)
     ssm_state = None if state is None else state["h"]
-    if use_pallas:
+    # ``use_pallas="attention"`` (the trainer on a TPU) selects attention's
+    # kernel alone: the ssd recurrence then stays on its XLA path.
+    if use_pallas is True:
         from repro.kernels import ops as kernel_ops
 
         y, new_h = kernel_ops.ssd(xh, dt, p["A_log"], Bm, Cm, state=ssm_state)

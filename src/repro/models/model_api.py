@@ -13,6 +13,10 @@ Batch layouts (all int32 tokens):
   train:   {"tokens": (B, S+1)} (+ "patches"/"frames" for vlm/encdec stubs)
   prefill: {"tokens": (B, S)} (+ stub inputs)
   decode:  {"tokens": (B, 1), "pos": () int32, "cache": pytree}
+
+``use_pallas``: ``False`` runs every layer on XLA, ``True`` every Pallas
+kernel the model has (attention, WKV6, SSD), ``"attention"`` attention's
+kernel alone (what the elastic trainer asks for on a TPU).
 """
 from __future__ import annotations
 

@@ -189,7 +189,9 @@ def time_mix(tm, x, cfg, state=None, x_last=None, use_pallas=False):
     v = (xv @ tm["wv"].astype(dt)).reshape(B, S, H, hd)
     g = jax.nn.silu(xg @ tm["wg"].astype(dt))
     lw = _decay(tm, xw).reshape(B, S, H, hd)
-    if use_pallas:
+    # ``use_pallas="attention"`` (the trainer on a TPU) selects attention's
+    # kernel alone: the wkv6 recurrence then stays on its XLA path.
+    if use_pallas is True:
         from repro.kernels import ops as kernel_ops
 
         o, new_state = kernel_ops.wkv6(r, k, v, lw, tm["u"], state=state)

@@ -1,19 +1,23 @@
-"""The main path's Pallas kernels compile for a TPU v5e at GPT-2 S widths.
+"""The main path's Pallas kernels compile for a TPU v5e at GPT-2 widths.
 
 Nothing runs: each test lowers a kernel with ``interpret=False`` for one chip
-of a described ``v5e:2x2`` topology and compiles it with the TPU compiler,
-which refuses block shapes the chip cannot tile. The topology is described
-inside a fixture, so the TPU library is loaded only by the worker that runs
-this file, and the tests skip where it cannot be described.
+(or all four) of a described ``v5e:2x2`` topology and compiles it with the
+TPU compiler, which refuses block shapes the chip cannot tile. The topology
+is described inside a fixture, so the TPU library is loaded only by the
+worker that runs this file, and the tests skip where it cannot be described.
 """
 import os
+import re
+from dataclasses import replace
 
 import pytest
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.flash_attention import flash_attention_kernel
+from repro.kernels import ops
+from repro.kernels.flash_attention import flash_attention_fwd
+from repro.models.layers import MaskSpec
 from repro.kernels.shard_codec import (
     Q_BLOCK,
     shard_decode_kernel,
@@ -27,11 +31,10 @@ CODEC_NB = [150_771, 300, 3, 9_216]
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -43,9 +46,16 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(fn, *shapes):
@@ -68,6 +78,83 @@ def test_flash_attention_compiles_for_v5e(one_chip):
     """GPT-2 S attention: batch 8, sequence 1024, 12 heads of 64."""
     q = jax.ShapeDtypeStruct((8, 1024, 12, 64), jnp.bfloat16,
                              sharding=one_chip)
-    hlo = _compile(lambda q, k, v: flash_attention_kernel(
+    hlo = _compile(lambda q, k, v: flash_attention_fwd(
         q, k, v, scale=0.125, interpret=False), q, q, q)
     assert "tpu_custom_call" in hlo
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """The kernels compile to Mosaic although the default backend is the
+    CPU."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+
+
+def _custom_calls(hlo):
+    """The ``op_name`` of each Pallas kernel in a compiled program."""
+    return [re.search(r'op_name="([^"]*)"', line).group(1)
+            for line in hlo.splitlines()
+            if "custom_call_target=\"tpu_custom_call\"" in line]
+
+
+def test_flash_attention_op_compiles_for_v5e(one_chip, mosaic):
+    """GPT-2 M attention, forward and backward through ops.flash_attention:
+    batch 8, sequence 1024, 16 heads of 64, bf16; three kernels (the
+    forward with its residuals, dK/dV, dQ) and no S x S buffer."""
+    x = jax.ShapeDtypeStruct((8, 1024, 16, 64), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        o = ops.flash_attention(q, k, v, MaskSpec("causal"), scale=0.125)
+        return jnp.sum(o.astype(jnp.float32))
+
+    hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), x, x, x)
+    assert len(_custom_calls(hlo)) == 3
+    assert "1024,1024]" not in hlo
+
+
+def test_flash_attention_op_compiles_per_shard_for_v5e_2x2(topo, mosaic):
+    """GPT-2 S attention on a (data 2, model 2) mesh of four chips, set as
+    the trainer sets it: the kernels run per shard on local arrays (16 rows
+    x 6 heads), and nothing is gathered."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    x = jax.ShapeDtypeStruct(
+        (32, 1024, 12, 64), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("data", None, "model", None)))
+
+    def loss(q, k, v):
+        o = ops.flash_attention(q, k, v, MaskSpec("causal"), scale=0.125)
+        return jnp.sum(o.astype(jnp.float32))
+
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), x, x, x)
+    assert len(_custom_calls(hlo)) == 3
+    assert re.search(r"bf16\[96,1024,64\]\S* custom-call", hlo)
+    assert "all-gather" not in hlo
+
+
+def test_train_step_runs_attention_in_the_kernel(one_chip, mosaic):
+    """A 2-layer train step at GPT-2 M's width and batch with the kernel
+    selected: every kernel, forward (and recomputed forward) and backward,
+    carries the `attention_core` scope that `attn_core_share` reads, and no
+    S x S score buffer is left in f32 or bf16."""
+    from repro.configs import get_config
+    from repro.models import build_model
+
+    cfg = replace(get_config("gpt2-medium"), n_layers=2)
+    model = build_model(cfg)
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        model.train_state_specs())
+    batch = {"tokens": jax.ShapeDtypeStruct((8, 1025), jnp.int32,
+                                            sharding=one_chip)}
+    hlo = _compile(model.make_train_step(use_pallas="attention"), state,
+                   batch)
+    calls = _custom_calls(hlo)
+    assert len(calls) == 4, calls
+    assert all("attention_core" in op for op in calls), calls
+    assert any(op.startswith("jit(train_step)/transpose(") for op in calls)
+    assert not re.search(r"(f32|bf16)\[8,16,1024,1024\]", hlo)

@@ -157,3 +157,163 @@ def test_step_compile_timed_apart_from_step_time():
     assert tr.compile_seconds[(1, 1)] > 0
     times = tr.metrics_snapshot()["step_times"][1]
     assert len(times) == 2 and all(t > 0 for t in times)
+
+
+@pytest.mark.parametrize("layout", [(1, 1), (2, 1), (4, 2)],
+                         ids=["1x1", "2x1", "4x2"])
+def test_kernel_step_matches_xla_step(layout):
+    """With the kernel selected (the backend predicate patched, so that the
+    kernel runs in the interpreter), one step of a tiny GPT-2 matches the
+    XLA path's step on the same layout: the loss, the gradient (AdamW's
+    first moment after one step) and each parameter's change. On (2, 1) and
+    (4, 2) the kernel runs per shard, over data and over model. The compile
+    span and the site counter name the path each step took."""
+    n, tp = layout
+    out = _run(f"""
+        import jax, numpy as np
+        import repro.elastic.trainer as T
+        from repro.configs import get_config
+        from repro.data.synthetic import TokenStream
+        from repro.elastic import ElasticTrainer
+        from repro.models import build_model
+
+        cfg = get_config("gpt2").reduced()
+        res = {{}}
+        for impl in ("xla", "pallas"):
+            T.kernels_selected = lambda: impl == "pallas"
+            tr = ElasticTrainer(build_model(cfg), initial={n},
+                                per_device_batch=2)
+            tr.init(jax.random.PRNGKey(0))
+            if {tp} > 1:
+                tr.apply_reshard({tp})
+            stream = TokenStream(vocab=cfg.vocab, seq_len=128, seed=0)
+            p0 = jax.tree.map(np.asarray, tr.state["params"])
+            m = tr.step({{"tokens": stream.batch(range(tr.global_batch))}})
+            (comp,) = tr.tracer.named("chaos.compile")
+            assert comp.attrs["attention"] == impl, comp.attrs
+            sites = tr.tracer.counter("chaos_attention_sites_total")
+            assert sites == {{(impl, "{n}", "{tp}"): 1.0}}, sites
+            res[impl] = m, jax.tree.map(np.asarray, tr.state), p0
+        (mx, sx, p0), (mk, sk, _) = res["xla"], res["pallas"]
+        assert abs(mx["loss"] - mk["loss"]) < 1e-4, (mx, mk)
+        assert abs(mx["grad_norm"] - mk["grad_norm"]) < 1e-2 * mx["grad_norm"]
+        gaps = [np.linalg.norm(a - b) / np.linalg.norm(a) for a, b in zip(
+            jax.tree.leaves(sx["opt"]["m"]), jax.tree.leaves(sk["opt"]["m"]))]
+        assert max(gaps) < 2e-2, gaps
+        # Each leaf's change, the kernel's against XLA's, as a share of
+        # XLA's. AdamW's first step is about lr times the gradient's sign,
+        # so leaves with gradients near zero read up to about 0.25 here; an
+        # update of the wrong sign would read 2, one left out 1.
+        moves = [np.linalg.norm(b - a) / np.linalg.norm(a - o)
+                 for a, b, o in zip(jax.tree.leaves(sx["params"]),
+                                    jax.tree.leaves(sk["params"]),
+                                    jax.tree.leaves(p0))]
+        assert max(moves) < 0.5, moves
+        print("OK kernel step", max(gaps), max(moves))
+    """, devices=4)
+    assert "OK kernel step" in out
+
+
+def test_kernel_step_reused_on_other_devices():
+    """A layout's step, compiled with the kernel selected on chips {0, 1},
+    runs again after chip 1 fails and the trainer is back to two chips,
+    {0, 2}: the step is traced and called under the layout's abstract
+    mesh, which names no devices, so it is reused without a recompile."""
+    out = _run("""
+        import jax, numpy as np
+        import repro.elastic.trainer as T
+        from repro.configs import get_config
+        from repro.data.synthetic import TokenStream
+        from repro.elastic import ElasticTrainer
+        from repro.models import build_model
+
+        T.kernels_selected = lambda: True
+        cfg = get_config("gpt2").reduced()
+        tr = ElasticTrainer(build_model(cfg), initial=2, per_device_batch=2)
+        tr.init(jax.random.PRNGKey(0))
+        stream = TokenStream(vocab=cfg.vocab, seq_len=128, seed=0)
+        losses = []
+        for move in (None, lambda: tr.scale_out(),
+                     lambda: tr.scale_in(tr.active[1], failure=True)):
+            if move:
+                move()
+            batch = {"tokens": stream.batch(range(tr.global_batch))}
+            losses.append(tr.step(batch)["loss"])
+        assert [d.id for d in tr.active] == [0, 2], tr.active
+        assert np.all(np.isfinite(losses)), losses
+        assert tr.tracer.counter("chaos_compiles_total") == {
+            ("2", "1"): 1.0, ("3", "1"): 1.0}
+        assert tr.tracer.counter("chaos_attention_sites_total") == {
+            ("pallas", "2", "1"): 1.0, ("pallas", "3", "1"): 1.0}
+        print("OK reused")
+    """, devices=4)
+    assert "OK reused" in out
+
+
+def test_traced_window_flag_stays_on_xla(monkeypatch):
+    """Gemma2's alternating local and global layers carry a traced window
+    flag, which the kernel's contract excludes: with the kernel selected the
+    step records the XLA path and trains."""
+    import jax
+    import numpy as np
+
+    import repro.elastic.trainer as T
+    from repro.configs import get_config
+    from repro.data.synthetic import TokenStream
+    from repro.elastic import ElasticTrainer
+    from repro.models import build_model
+
+    monkeypatch.setattr(T, "kernels_selected", lambda: True)
+    cfg = get_config("gemma2-27b").reduced()
+    assert cfg.alt_local_global
+    tr = ElasticTrainer(build_model(cfg), devices=jax.devices()[:1],
+                        initial=1, per_device_batch=2)
+    tr.init()
+    stream = TokenStream(vocab=cfg.vocab, seq_len=128, seed=0)
+    losses = [tr.step({"tokens": stream.batch(range(2))})["loss"]
+              for _ in range(2)]
+    (comp,) = tr.tracer.named("chaos.compile")
+    assert comp.attrs["attention"] == "xla"
+    assert tr.tracer.counter("chaos_attention_sites_total") == {
+        ("xla", "1", "1"): 1.0}
+    assert np.all(np.isfinite(losses))
+
+
+@pytest.mark.parametrize("arch", ["rwkv6-1.6b", "zamba2-1.2b"])
+def test_selection_keeps_recurrences_on_xla(arch, monkeypatch):
+    """The trainer's selection asks for attention's kernel alone: under it
+    the WKV6 and SSD recurrences trace no Pallas call (their kernels have
+    no per-shard path), where ``use_pallas=True`` traces one. At sequence
+    64 attention is below the kernel's block, so a Pallas call here could
+    only be the recurrence's. The step trains."""
+    import jax
+    import numpy as np
+
+    import repro.elastic.trainer as T
+    from repro.configs import get_config
+    from repro.data.synthetic import TokenStream
+    from repro.elastic import ElasticTrainer
+    from repro.models import build_model
+
+    monkeypatch.setattr(T, "kernels_selected", lambda: True)
+    model = build_model(get_config(arch).reduced())
+    asked = []
+    make = model.make_train_step
+    monkeypatch.setattr(model, "make_train_step",
+                        lambda **kw: asked.append(kw) or make(**kw))
+    tr = ElasticTrainer(model, devices=jax.devices()[:1], initial=1,
+                        per_device_batch=2)
+    tr.init()
+    batch = {"tokens": TokenStream(vocab=model.cfg.vocab, seq_len=64,
+                                   seed=0).batch(range(2))}
+    params = jax.tree.map(np.asarray, tr.state["params"])
+    assert np.isfinite(tr.step(batch)["loss"])
+    (kw,) = asked
+
+    def pallas_calls(use_pallas):
+        jaxpr = jax.make_jaxpr(lambda p: model.loss_fn(
+            p, batch, use_pallas=use_pallas))(params)
+        return str(jaxpr).count("pallas_call")
+
+    assert pallas_calls(kw["use_pallas"]) == 0
+    assert pallas_calls(True) > 0

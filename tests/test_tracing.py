@@ -133,8 +133,10 @@ def test_one_step_tree_per_step(one_device_trainer):
 def test_one_compile_per_layout(one_device_trainer):
     tr = one_device_trainer
     (comp,) = tr.tracer.named("chaos.compile")
-    assert comp.attrs == {"n": 1, "tp": 1}
+    assert comp.attrs == {"n": 1, "tp": 1, "attention": "xla"}
     assert tr.tracer.counter("chaos_compiles_total") == {("1", "1"): 1.0}
+    assert tr.tracer.counter("chaos_attention_sites_total") == {
+        ("xla", "1", "1"): 1.0}
     assert tr.compile_seconds == {(1, 1): comp.duration_s}
     assert list(tr._compiled) == [(1, 1)]
 
