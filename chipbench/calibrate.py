@@ -12,9 +12,10 @@ float8 e4m3, one step below the configuration's bfloat16 compute), the
 reference with bfloat16 parameters (one step below their float32), and the
 faults that leave part of the batch out (half of each batch; on several
 chips, every step on one data-parallel replica's rows: the exchange between
-chips left out), and an update dropped (the first layer's MLP input matrix
-left at its old value). A step that returns its state unchanged reads 1 on
-`grad_gap` and `change_gap` without a run. Control seeds need one chip.
+chips left out), and an update dropped (the first layer of the family's
+`UPDATE_LEAF` left at its old value). A step that returns its state
+unchanged reads 1 on `grad_gap` and `change_gap` without a run. Control
+seeds need one chip.
 Writes one JSON object per reading to `--out` and prints them.
 """
 import argparse

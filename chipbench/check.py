@@ -13,8 +13,9 @@ Three numbers, each against a limit of its own (`chipbench/limits/<cell>.json`):
   leaving out leaves whose reference gradient is under a thousandth of the
   median leaf's (round-off alone moves them under AdamW).
 
-A leaf is one parameter of one layer: stacked layer parameters are split
-along their leading axis.
+A leaf is one parameter of one layer: the leaves under the family's
+`STACKED` top-level keys hold one layer per row of their leading axis and
+are split along it.
 
 Two steps, not three: at GPT-2 M's lr 3e-4 without warm-up the third step
 of some seeds lands in a loss spike (loss back up from 10.3 to 12.2), where
@@ -43,10 +44,10 @@ def _norms(leaves, stacked):
         for x, s in zip(leaves, stacked)]
 
 
-def _layout(tree):
+def _layout(tree, stacked_keys):
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)
     names = [jax.tree_util.keystr(p) for p, _ in flat]
-    stacked = tuple("['layers']" in n for n in names)
+    stacked = tuple(p[0].key in stacked_keys for p, _ in flat)
     return names, [x for _, x in flat], stacked
 
 
@@ -67,15 +68,15 @@ def _named(names, stacked, values) -> dict:
     return out
 
 
-def leaf_norms(tree, scale: float = 1.0) -> dict:
-    names, leaves, stacked = _layout(tree)
+def leaf_norms(tree, stacked_keys, scale: float = 1.0) -> dict:
+    names, leaves, stacked = _layout(tree, stacked_keys)
     return {k: v * scale for k, v in
             _named(names, stacked, _norms_jit(leaves, stacked)).items()}
 
 
-def change_norms(new, old) -> dict:
+def change_norms(new, old, stacked_keys) -> dict:
     """Per-leaf norms of new - old, computed where `new` lives."""
-    names, a, stacked = _layout(new)
+    names, a, stacked = _layout(new, stacked_keys)
     b = [jax.device_put(y, x.sharding)
          for x, y in zip(a, jax.tree.leaves(old))]
     return _named(names, stacked, _diff_norms_jit(a, b, stacked))

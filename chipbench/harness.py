@@ -8,7 +8,9 @@ and one more unit of the traffic to warm every layout. The window then runs
 whole units (an event cycle through `ChurnEngine(TrainerBackend)`, or
 `steps_between` steps) until `--seconds` have passed. After the window the
 trainer's state is freed and the plain reference retraces the first
-CHECK_STEPS steps for the comparison in `check.py`.
+CHECK_STEPS steps for the comparison in `check.py`. The reference is the
+module of the configuration's family, `chipbench.reference.<model_type>`
+(its interface: `chipbench/reference/__init__.py`).
 
 Spans are recorded by this file's own code around its calls into the
 program: every `ElasticTrainer.step`, every `TrainerBackend.handle`, and the
@@ -26,7 +28,6 @@ import shutil
 import sys
 import time
 from contextlib import contextmanager, nullcontext
-from dataclasses import replace
 from functools import partial
 from pathlib import Path
 
@@ -41,7 +42,6 @@ from repro.models import build_model
 
 from chipbench import check, feed, trace_reduce
 from chipbench.moves import MoveChecker
-from chipbench.reference import gpt2 as ref
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
@@ -74,26 +74,25 @@ def load_cell(name: str, root: Path = ROOT) -> dict:
     return cell
 
 
+def family(cfg: dict):
+    """The reference module of the config file's model family."""
+    return importlib.import_module(f"chipbench.reference.{cfg['model_type']}")
+
+
 def program_model(cfg: dict, rehearse: bool):
-    """The program's model for a config file, checked against the file."""
+    """The program's model for a config file, checked against the file:
+    (model, config file as run, reference sizes)."""
+    ref = family(cfg)
     arch = get_config(cfg["registry_name"])
     if rehearse:
-        small = arch.reduced()
-        arch = replace(small, n_kv_heads=small.n_heads)  # GPT-2 has no GQA
-        cfg = dict(cfg, n_layer=arch.n_layers, n_embd=arch.d_model,
-                   n_head=arch.n_heads, n_inner=arch.d_ff,
-                   vocab_size=arch.vocab,
-                   train=dict(cfg["train"], **REHEARSE_SIZES))
+        cfg, arch = ref.rehearse_cfg(cfg, arch.reduced())
+        cfg = dict(cfg, train=dict(cfg["train"], **REHEARSE_SIZES))
     t = cfg["train"]
-    want = {"n_layers": cfg["n_layer"], "d_model": cfg["n_embd"],
-            "n_heads": cfg["n_head"], "n_kv_heads": cfg["n_head"],
-            "d_ff": cfg["n_inner"] or 4 * cfg["n_embd"],
-            "vocab": cfg["vocab_size"], "norm_eps": cfg["layer_norm_epsilon"],
-            "learning_rate": t["learning_rate"],
-            "weight_decay": t["weight_decay"], "grad_clip": t["grad_clip"],
-            "dtype": t["compute_dtype"], "param_dtype": t["param_dtype"],
-            "optimizer": t["optimizer"], "tie_embeddings": True,
-            "positions": "learned", "norm": "layernorm", "mlp": "gelu2"}
+    want = dict(ref.program_fields(cfg),
+                learning_rate=t["learning_rate"],
+                weight_decay=t["weight_decay"], grad_clip=t["grad_clip"],
+                dtype=t["compute_dtype"], param_dtype=t["param_dtype"],
+                optimizer=t["optimizer"])
     got = {k: getattr(arch, k) for k in want}
     if got != want:
         raise ValueError(f"program config {arch.name} differs from the "
@@ -157,7 +156,7 @@ class TimedTrainer(ElasticTrainer):
 
 class TimedBackend:
     """Proxy of a `TrainerBackend` for the engine: a span around every
-    `handle`, the trainer's move seconds for the event, and the move check."""
+    `handle` with the layouts before and after it, and the move check."""
 
     def __init__(self, backend: TrainerBackend, spans: Spans,
                  mover: MoveChecker):
@@ -176,10 +175,8 @@ class TimedBackend:
         with self.spans.span("pause"):
             before = self.mover.fingerprint(tr.state)
         layout = (len(tr.active), tr.tp)
-        k = len(tr.events)
         with self.spans.span("handle", kind=ev.kind) as rec:
             self.backend.handle(seq, ev, ledger)
-        rec["move_s"] = sum(e.wall_s for e in tr.events[k:])
         rec["layout"] = [layout, (len(tr.active), tr.tp)]
         with self.spans.span("pause"):
             after = self.mover.fingerprint(tr.state)
@@ -218,6 +215,7 @@ class Run:
     def __init__(self, cell: dict, seed: int, *, trace: bool, rehearse: bool,
                  t_start: float, out_dir: Path):
         self.cell = cell
+        self.ref = family(cell["cfg"])
         self.seed = seed
         self.trace = trace
         self.rehearse = rehearse
@@ -244,7 +242,7 @@ class Run:
         marks.append(("warm-up unit", time.perf_counter()))
         self.setup_s = marks[-1][1] - self.t_start
         t = self.t_start
-        _log("set-up: " + ", ".join(
+        _log(f"set-up ({self.ref.__name__}): " + ", ".join(
             f"{name} {m - t0:.2f} s" for (name, m), t0
             in zip(marks, [t] + [m for _, m in marks[:-1]])))
 
@@ -271,9 +269,9 @@ class Run:
         pool of rows."""
         self.seed = seed
         tr = self.trainer
-        init = jax.jit(partial(ref.init_state, self.a),
+        init = jax.jit(partial(self.ref.init_state, self.a),
                        out_shardings=NamedSharding(tr.mesh(), P()))
-        tr.state = init(ref.seed_key(seed))
+        tr.state = init(self.ref.seed_key(seed))
         self.feed = feed.Feed(self.rows(seed))
 
     def rows(self, seed: int):
@@ -295,7 +293,7 @@ class Run:
     def check_pass(self):
         """The first steps, through the window's own calls and feed, with the
         reads of the state the comparison needs."""
-        tr, b1 = self.trainer, self.a["b1"]
+        tr, b1 = self.trainer, self.cfg["train"]["adam_b1"]
         p0 = jax.jit(lambda t: jax.tree.map(jnp.copy, t))(tr.state["params"])
         got = {"losses": [], "grad_norms": []}
 
@@ -306,10 +304,11 @@ class Run:
             with self.spans.span("pause"):
                 if k == 1:
                     got["grad"] = check.leaf_norms(trainer.state["opt"]["m"],
+                                                   self.ref.STACKED,
                                                    1.0 / (1.0 - b1))
                 if k == check.CHECK_STEPS:
                     got["change"] = check.change_norms(
-                        trainer.state["params"], p0)
+                        trainer.state["params"], p0, self.ref.STACKED)
 
         tr.after_step = after_step
         try:
@@ -445,13 +444,14 @@ class Run:
     def reference_readout(self, *, quant=None, param_dtype=None,
                           rows_kept=None, drop_update=False) -> dict:
         """The reference's first steps over the same rows, its state on the
-        first chip, in blocks of rows that fit dealt out to the cell's chips. `quant` / `param_dtype` give the
-        control; `rows_kept[k]` keeps that share of step k's first rows
-        (the faults that leave part of the batch out); `drop_update` keeps
-        the first layer's MLP input matrix at its old value (an answer
-        altered where it is made)."""
+        first chip, in blocks of rows that fit dealt out to the cell's
+        chips. `quant` / `param_dtype` give the control; `rows_kept[k]`
+        keeps that share of step k's first rows (the faults that leave part
+        of the batch out); `drop_update` keeps the first layer of the
+        family's `UPDATE_LEAF` at its old value (an answer altered where it
+        is made)."""
         dev = self.pool[0]
-        a = self.a
+        a, ref = self.a, self.ref
         init = jax.jit(partial(ref.init_state, a),
                        out_shardings=SingleDeviceSharding(dev))
 
@@ -468,32 +468,29 @@ class Run:
         for k, rows in enumerate(self.check_batches):
             if rows_kept is not None:
                 rows = rows[:max(1, int(len(rows) * rows_kept[k]))]
-            old = state["params"]["layers"]["mlp"]["w1"]
+            old = state["params"] if drop_update else None
             state, loss, grads, gnorm = ref.train_step(
                 a, state, rows, self.pool, quant=quant,
                 param_dtype=param_dtype, rows_per_block=self.ref_rows())
             if drop_update:
-                w1 = state["params"]["layers"]["mlp"]["w1"]
-                state["params"]["layers"]["mlp"]["w1"] = w1.at[0].set(old[0])
+                state["params"] = keep_first_layer(state["params"], old,
+                                                   ref.UPDATE_LEAF)
             del old
             got["losses"].append(loss)
             got["grad_norms"].append(gnorm)
             if k == 0:
-                got["grad"] = check.leaf_norms(grads)
+                got["grad"] = check.leaf_norms(grads, ref.STACKED)
             del grads
         params = state["params"]
         del state
-        got["change"] = check.change_norms(params, start()["params"])
+        got["change"] = check.change_norms(params, start()["params"],
+                                           ref.STACKED)
         return got
 
     def ref_rows(self) -> int:
-        """Rows per block of the reference, a power of two: f32 activations
-        of at most REF_BLOCK_BYTES (per position: each layer's input, one layer's ten
-        d-wide and two S-wide arrays, and the logits with their softmax and
-        its gradient)."""
-        a, s = self.a, self.seq_len
-        per_row = 4 * s * (a["L"] * a["d"] + 10 * a["d"] + 2 * a["H"] * s
-                           + 3 * a["V"])
+        """Rows per block of the reference, a power of two: activations of
+        at most REF_BLOCK_BYTES."""
+        per_row = self.ref.activation_bytes_per_row(self.a, self.seq_len)
         rows = 1
         while rows < 8 and 2 * rows * per_row <= REF_BLOCK_BYTES:
             rows *= 2
@@ -565,6 +562,15 @@ def execute(cell_name: str, seed: int, seconds: float, trace: bool, *,
         _log(f"check {k}: {j['value']!r} limit {j['limit']!r} "
              f"{'ok' if j['ok'] else 'FAIL'}")
     return result
+
+
+def keep_first_layer(new: dict, old: dict, path: tuple) -> dict:
+    """`new` with the first layer of the stacked leaf at `path` taken from
+    `old`: an update dropped where it is made."""
+    if len(path) == 1:
+        return dict(new, **{path[0]: new[path[0]].at[0].set(old[path[0]][0])})
+    return dict(new, **{path[0]: keep_first_layer(new[path[0]], old[path[0]],
+                                                  path[1:])})
 
 
 def _log(msg: str):

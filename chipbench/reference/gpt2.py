@@ -19,10 +19,15 @@ below the configuration's bfloat16 compute: the operands of every matmul
 are rounded (straight through in the backward pass) to float8 e4m3 with one
 scale per tensor. (A bfloat16 rounding written as two converts is no
 control: with XLA's excess precision the TPU compiles it away.)
+
+The rest of the family interface (`chipbench/reference/__init__.py`): the
+program's fields, the rehearsal size, the PaLM FLOP count, the size of a
+row's activations, and the leaves the faults and `check.py` name.
 """
 from __future__ import annotations
 
 import math
+from dataclasses import replace
 from functools import partial
 
 import jax
@@ -30,6 +35,8 @@ import jax.numpy as jnp
 from jax import lax
 
 F8_MAX = 448.0  # largest finite float8_e4m3fn
+UPDATE_LEAF = ("layers", "mlp", "w1")  # the MLP input matrix
+STACKED = ("layers",)
 
 
 def arch(cfg: dict) -> dict:
@@ -45,6 +52,53 @@ def arch(cfg: dict) -> dict:
         "adam_eps": t["adam_eps"], "wd": t["weight_decay"],
         "clip": t["grad_clip"],
     }
+
+
+def program_fields(cfg: dict) -> dict:
+    """The registry's `ArchConfig` model fields that must equal the file's."""
+    return {"n_layers": cfg["n_layer"], "d_model": cfg["n_embd"],
+            "n_heads": cfg["n_head"], "n_kv_heads": cfg["n_head"],
+            "d_ff": cfg["n_inner"] or 4 * cfg["n_embd"],
+            "vocab": cfg["vocab_size"], "norm_eps": cfg["layer_norm_epsilon"],
+            "tie_embeddings": True, "positions": "learned",
+            "norm": "layernorm", "mlp": "gelu2"}
+
+
+def rehearse_cfg(cfg: dict, small):
+    """(config file, `ArchConfig`) at the size of the registry's reduced
+    config `small`, which GPT-2's lack of GQA keeps at n_kv_heads = n_heads."""
+    small = replace(small, n_kv_heads=small.n_heads)
+    return dict(cfg, n_layer=small.n_layers, n_embd=small.d_model,
+                n_head=small.n_heads, n_inner=small.d_ff,
+                vocab_size=small.vocab), small
+
+
+def matmul_params(cfg: dict) -> int:
+    d = cfg["n_embd"]
+    ff = cfg["n_inner"] or 4 * d
+    return cfg["n_layer"] * (4 * d * d + 2 * d * ff) + cfg["vocab_size"] * d
+
+
+def train_flops_per_token(cfg: dict) -> int:
+    """The PaLM count (Chowdhery et al. 2022, App. B): 6 N for the forward
+    and backward matmuls over the N matmul parameters, plus 12 L S d for the
+    attention scores and their weighted sums (causal masking not
+    subtracted). N counts the four attention projections and the two MLP
+    matrices of every layer and the head, which is tied to the token
+    embedding but still a matmul; the learned position table and the
+    embedding lookup do no matmul and are left out, as are layer norms.
+    Recomputed work (remat) is not counted: it is not required."""
+    seq = cfg["train"]["seq_len"]
+    return (6 * matmul_params(cfg)
+            + 12 * cfg["n_layer"] * seq * cfg["n_embd"])
+
+
+def activation_bytes_per_row(a: dict, seq_len: int) -> int:
+    """f32 bytes live for one row in `train_step`'s blocks: per position,
+    each layer's input, one layer's ten d-wide and two S-wide arrays, and
+    the logits with their softmax and its gradient."""
+    return 4 * seq_len * (a["L"] * a["d"] + 10 * a["d"]
+                          + 2 * a["H"] * seq_len + 3 * a["V"])
 
 
 # ---------------------------------------------------------------------------

@@ -2,10 +2,7 @@
 driven in a child process with `--rehearse` (reduced sizes, virtual CPU
 devices), so the test process itself never needs a chip.
 
-They run against a copy of the benchmark (`bench_root`) whose
-`BENCHMARK.json` also holds the cells of `data/held_cells.json`, if that file
-exists: cells whose code and data are here but which are not yet in the
-benchmark, so that their path stays tested."""
+They run against a copy of the benchmark (`bench_root`)."""
 import json
 import os
 import shutil
@@ -16,17 +13,11 @@ from pathlib import Path
 import pytest
 
 ROOT = Path(__file__).resolve().parents[2]
-HELD = Path(__file__).resolve().parent / "data" / "held_cells.json"
 IGNORE = shutil.ignore_patterns(".jax_cache", "out", "scratch", "__pycache__")
 
 
-def merged_bench() -> dict:
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    if HELD.exists():
-        for key, entries in json.loads(HELD.read_text()).items():
-            names = {e["name"] for e in bench[key]}
-            bench[key] += [e for e in entries if e["name"] not in names]
-    return bench
+def load_bench() -> dict:
+    return json.loads((ROOT / "BENCHMARK.json").read_text())
 
 
 def make_root(path: Path, bench: dict) -> Path:
@@ -44,7 +35,7 @@ def last_json(stdout: str):
 
 @pytest.fixture(scope="session")
 def bench_root(tmp_path_factory):
-    return make_root(tmp_path_factory.mktemp("bench"), merged_bench())
+    return make_root(tmp_path_factory.mktemp("bench"), load_bench())
 
 
 @pytest.fixture

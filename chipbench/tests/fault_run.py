@@ -7,8 +7,8 @@ Faults: `none`; `unchanged` (the step returns its state unchanged);
 `half_batch` (the step sees the first half of its batch, the mean taken over
 it); `no_exchange` (on several chips, each chip steps on its own rows with no
 gradient exchange); `dropped_update` (an answer altered where it is made:
-the first layer's MLP input matrix keeps its old value). Prints the run's
-result line last.
+the first layer of the family's `UPDATE_LEAF` keeps its old value). Prints
+the run's result line last.
 """
 import argparse
 import json
@@ -24,7 +24,9 @@ from run import setup_env  # noqa: E402
 FAULTS = ("none", "unchanged", "half_batch", "no_exchange", "dropped_update")
 
 
-def broken_step(step, fault):
+def broken_step(step, fault, leaf):
+    from chipbench.harness import keep_first_layer
+
     def unchanged(state, batch):
         return state, step(state, batch)[1]
 
@@ -34,10 +36,8 @@ def broken_step(step, fault):
 
     def dropped_update(state, batch):
         new, metrics = step(state, batch)
-        w1 = new["params"]["layers"]["mlp"]["w1"]
-        old = state["params"]["layers"]["mlp"]["w1"]
-        new["params"]["layers"]["mlp"]["w1"] = w1.at[0].set(old[0])
-        return new, metrics
+        params = keep_first_layer(new["params"], state["params"], leaf)
+        return dict(new, params=params), metrics
 
     return {"unchanged": unchanged, "half_batch": half_batch,
             "dropped_update": dropped_update}[fault]
@@ -59,14 +59,16 @@ def main(argv=None) -> int:
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from chipbench.harness import TimedTrainer, execute
+    from chipbench.harness import TimedTrainer, execute, family, load_cell
     from repro.models.model_api import Model
 
     trainer_cls = TimedTrainer
     if args.fault in ("unchanged", "half_batch", "dropped_update"):
+        leaf = family(load_cell(args.workload)["cfg"]).UPDATE_LEAF
         make = Model.make_train_step
         Model.make_train_step = (
-            lambda self, **kw: broken_step(make(self, **kw), args.fault))
+            lambda self, **kw: broken_step(make(self, **kw), args.fault,
+                                           leaf))
     elif args.fault == "no_exchange":
         class NoExchange(TimedTrainer):
             def _get_step_fn(self, n, batch):
