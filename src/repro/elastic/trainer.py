@@ -6,9 +6,11 @@ synchronous data-parallel training over a device mesh that grows and shrinks
 
   * scale-out: a joining device gets the training state via a Chaos
     replication plan (Algorithm 1/2 over a synthetic per-device link model);
-    physically the state moves with ``jax.device_put`` onto the enlarged
-    mesh, and the plan's byte accounting (+ optional int8 shard codec) is
-    reported like the paper's Fig 7;
+    physically the state moves chip to chip, never through the host
+    (``ElasticTrainer._move``: a compiled relayout on the devices that hold
+    a leaf where no device holds a new block whole, then
+    ``jax.device_put``), and the plan's byte accounting (+ optional int8
+    shard codec) is reported like the paper's Fig 7;
   * scale-in / failure: the mesh shrinks; state survives on the remaining
     replicas (synchronous DP ⇒ identical state — the paper's §III premise);
     a failed device additionally exercises the MemoryReplicaStore restore;
@@ -27,8 +29,8 @@ synchronous data-parallel training over a device mesh that grows and shrinks
     ``read_metrics``), ``chaos.compile``, ``chaos.move`` (``plan``,
     ``codec``, ``transfer``, ``on_reshard``) and the backend's
     ``chaos.handle``, with the counters ``chaos_compiles_total``,
-    ``chaos_compile_seconds_total``, ``chaos_attention_sites_total`` and
-    ``chaos_move_bytes_total``;
+    ``chaos_compile_seconds_total``, ``chaos_attention_sites_total``,
+    ``chaos_move_bytes_total`` and ``chaos_move_leaves_total``;
   * on a TPU the step runs attention's core in the Pallas flash-attention
     kernel, forward and backward (``kernels/ops.flash_attention``), per
     shard on a mesh of several devices; elsewhere it takes the XLA path.
@@ -86,6 +88,12 @@ def _box(index, shape) -> List[tuple]:
     return [sl.indices(dim)[:2] for sl, dim in zip(index, shape)]
 
 
+def _per_leaf(leaves, target) -> list:
+    """``target`` (a sharding, or a tree of them like the state) per leaf."""
+    return ([target] * len(leaves) if isinstance(target, jax.sharding.Sharding)
+            else jax.tree.leaves(target))
+
+
 def received_bytes(state, target) -> int:
     """Bytes the devices of ``target`` (a sharding, or a tree of them like
     ``state``) must receive to hold ``state`` there: per leaf and device, the
@@ -93,8 +101,7 @@ def received_bytes(state, target) -> int:
     that device held before. From the shardings' index maps alone; a leaf
     without a sharding (a host array) counts whole."""
     leaves = jax.tree.leaves(state)
-    targets = ([target] * len(leaves) if isinstance(target, jax.sharding.Sharding)
-               else jax.tree.leaves(target))
+    targets = _per_leaf(leaves, target)
     total = 0
     for leaf, new in zip(leaves, targets):
         shape = leaf.shape
@@ -109,6 +116,31 @@ def received_bytes(state, target) -> int:
                 want -= int(np.prod([max(0, b - a) for a, b in have]))
             total += want * leaf.dtype.itemsize
     return total
+
+
+def _blocks(sharding, shape) -> set:
+    return {tuple((sl.start, sl.stop, sl.step) for sl in index)
+            for index in sharding.devices_indices_map(shape).values()}
+
+
+def relayout_sharding(old, new, shape):
+    """The layout a leaf of ``shape`` held under ``old`` takes, on the devices
+    that hold it, before ``jax.device_put`` puts it under ``new``; None where
+    every block of ``new`` is a block of ``old``, whose buffer device_put
+    then reuses or copies device to device. A block that no device holds
+    whole is one device_put would assemble on the host (JAX 0.9's
+    ``shard_sharded_device_array_slow_path`` reads the whole array back and
+    uploads it again), so such a leaf is laid out anew first: as ``new``
+    itself where the devices are the same (a reshard: a local slice, or an
+    all-gather over the chips' links), else as a replica on ``old``'s
+    devices, whose whole arrays serve ``new``'s replicas."""
+    if _blocks(new, shape) <= _blocks(old, shape):
+        return None
+    if old.device_set == new.device_set:
+        return new
+    if not new.is_fully_replicated:
+        raise ValueError("a move onto other devices lands on replicas")
+    return NamedSharding(old.mesh, P())
 
 
 def kernels_selected() -> bool:
@@ -149,6 +181,9 @@ class ElasticTrainer:
         self._step_fns: Dict[tuple, Callable] = {}
         #: the executable behind each ``_step_fns`` entry, for its HLO text
         self._compiled: Dict[tuple, object] = {}
+        #: per (from, to) layout of a move: the state leaves it lays out anew
+        #: on their devices, and the compiled program that does it
+        self._relayouts: Dict[tuple, tuple] = {}
         #: every timing of this trainer: spans on the host's real clock
         self.tracer = Recorder()
         # Current parallelism layout: tp-ways of tensor parallelism over the
@@ -399,15 +434,44 @@ class ElasticTrainer:
 
     # -- state movement ---------------------------------------------------------------
 
+    def _relayout(self, key: tuple, sharding):
+        """The leaves of the state (flat indices) that a move onto
+        ``sharding`` (one, or a tree of them) lays out anew on their devices
+        (``relayout_sharding``), and the program that does it (None where
+        there are none): one per ``key``, the (from, to) layouts, compiled
+        ahead of its first call. It donates nothing, and it is not a
+        ``chaos.compile``, which counts train steps."""
+        if key not in self._relayouts:
+            leaves = jax.tree.leaves(self.state)
+            staged = {}
+            for i, (x, new) in enumerate(zip(leaves, _per_leaf(leaves,
+                                                               sharding))):
+                s = relayout_sharding(x.sharding, new, x.shape)
+                if s is not None:
+                    staged[i] = s
+            prog = None
+            if staged:
+                prog = jax.jit(lambda xs: xs,
+                               out_shardings=list(staged.values())).lower(
+                    [leaves[i] for i in staged]).compile()
+            self._relayouts[key] = (list(staged), prog)
+        return self._relayouts[key]
+
     def _move(self, kind: str, target, prepare, codec=None):
         """Moves the state onto a new layout in one ``chaos.move`` span:
         ``codec`` (the ``codec`` callable, when given), ``plan``
         (``prepare``, which switches the trainer to the new layout, then
-        ``target()``, its sharding or tree of them, and the bytes the
-        devices receive), ``transfer`` (``device_put`` until ready),
-        ``on_reshard``. Returns (``prepare``'s result, the codec's, the
-        bytes, codec + plan + transfer seconds)."""
+        ``target()``, its sharding or tree of them, the bytes the devices
+        receive, and the relayout program, compiled on the pair's first
+        move), ``transfer`` (the relayout of the leaves whose new blocks no
+        device holds whole, on the devices that hold them, then
+        ``device_put`` from device buffers, until ready; nothing passes
+        through the host), ``on_reshard``. The counter
+        ``chaos_move_leaves_total{kind,path}`` counts the leaves that took
+        each path. Returns (``prepare``'s result, the codec's, the bytes,
+        codec + plan + transfer seconds)."""
         tr = self.tracer
+        old = (tuple(self.device_ids()), self._tp)
         with tr.span("chaos.move", kind=kind,
                      **{"from": (len(self.active), self._tp)}) as mv:
             spans, coded = [], None
@@ -419,9 +483,16 @@ class ElasticTrainer:
                 prepared = prepare()
                 sharding = target()
                 moved = received_bytes(self.state, sharding)
+                picks, relayout = self._relayout(
+                    (old, (tuple(self.device_ids()), self._tp)), sharding)
             spans.append(sp)
             with tr.span("chaos.move.transfer") as sp:
-                self.state = jax.device_put(self.state, sharding)
+                leaves, tree = jax.tree.flatten(self.state)
+                if picks:
+                    laid = relayout([leaves[i] for i in picks])
+                    for i, x in zip(picks, laid):
+                        leaves[i] = x
+                self.state = jax.device_put(tree.unflatten(leaves), sharding)
                 jax.block_until_ready(self.state)
             spans.append(sp)
             mv.attrs.update(to=(len(self.active), self._tp), bytes=moved)
@@ -431,6 +502,13 @@ class ElasticTrainer:
         tr.count("chaos_move_bytes_total", moved,
                  help_text="Bytes devices received on layout changes",
                  kind=kind)
+        for path, n in (("relayout", len(picks)),
+                        ("copy", len(leaves) - len(picks))):
+            tr.count("chaos_move_leaves_total", n,
+                     help_text="State leaves moved on layout changes, by "
+                               "path: laid out anew on their devices, or "
+                               "copied from whole blocks",
+                     kind=kind, path=path)
         return prepared, coded, moved, sum(x.duration_s for x in spans)
 
     # -- elasticity -----------------------------------------------------------------
@@ -501,10 +579,10 @@ class ElasticTrainer:
             device = self.active[-1]
         if len(self.active) <= 1:
             raise RuntimeError("cannot scale below one device")
-        # Snapshot state on survivors BEFORE dropping the device. The
-        # device_put gathers any tp-sharded leaves back to full replicas on
-        # the survivor mesh (the replicate-only baseline); a reshard policy
-        # re-applies tensor parallelism afterwards.
+        # The move gathers any tp-sharded leaves back to full replicas on
+        # the old mesh, the leaving device included, before dropping it; the
+        # survivors keep their replicas (the replicate-only baseline). A
+        # reshard policy re-applies tensor parallelism afterwards.
         survivors = [d for d in self.active if d != device]
         kind = "node-failure" if failure else "scale-in"
 
@@ -521,12 +599,13 @@ class ElasticTrainer:
 
     def apply_reshard(self, tp: int, microbatch: int = 1) -> ScaleEvent:
         """Apply a parallelism-plan change on real arrays: rebuild the mesh
-        at (dp, tp) and ``jax.device_put`` every state leaf from its current
-        ``NamedSharding`` to the new layout's. GSPMD moves only the interval
-        deltas; a dp → tp reshard slices replicas in place and the reverse
-        all-gathers — both bit-identical round trips (tests mark the
-        real-array version ``slow``). Stop-free: the next step compiles at
-        most once per (n, tp)."""
+        at (dp, tp) and move every state leaf from its current
+        ``NamedSharding`` to the new layout's on the same devices, in one
+        compiled program: a dp → tp reshard slices replicas in place and the
+        reverse all-gathers — both bit-identical round trips (tests mark the
+        real-array version ``slow``); leaves whose blocks stay whole keep
+        their buffers. Stop-free: the next step compiles at most once per
+        (n, tp)."""
         tp = int(tp)
         n = len(self.active)
         if tp < 1 or n % tp:

@@ -317,3 +317,104 @@ def test_selection_keeps_recurrences_on_xla(arch, monkeypatch):
 
     assert pallas_calls(kw["use_pallas"]) == 0
     assert pallas_calls(True) > 0
+
+
+def test_moves_never_read_the_state_to_the_host():
+    """Every move of a churn cycle (two joins, a dp -> tp reshard to (4, 2),
+    a leave from (4, 2), a failure) keeps the state on the devices: no
+    leaf's value is fetched to the host inside a ``chaos.move.transfer``
+    span, and every leaf is bit-identical after the move. A plain
+    ``jax.device_put`` of the (4, 2) state onto three chips' replicas is
+    counted fetching, so the count can see a host round trip."""
+    out = _run("""
+        import contextlib
+        import jax, numpy as np
+        from jax._src import array
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from repro.configs import get_config
+        from repro.elastic import ElasticTrainer
+        from repro.models import build_model
+
+        fetches, live = [0], [False]
+        value = array.ArrayImpl._value
+
+        def spied(self):
+            fetches[0] += live[0]
+            return value.fget(self)
+
+        array.ArrayImpl._value = property(spied)
+        tr = ElasticTrainer(build_model(get_config("gpt2").reduced()),
+                            initial=2, per_device_batch=2)
+        tr.init(jax.random.PRNGKey(0))
+        span = tr.tracer.span
+
+        @contextlib.contextmanager
+        def counted(name, **attrs):
+            with span(name, **attrs) as sp:
+                live[0] = name == "chaos.move.transfer"
+                try:
+                    yield sp
+                finally:
+                    live[0] = False
+
+        tr.tracer.span = counted
+        host = lambda: [np.asarray(x) for x in jax.tree.leaves(tr.state)]
+        held = None
+        for kind, move in [("scale-out", tr.scale_out),
+                           ("scale-out", tr.scale_out),
+                           ("reshard", lambda: tr.apply_reshard(2)),
+                           ("scale-in", lambda: tr.scale_in(tr.active[3])),
+                           ("node-failure",
+                            lambda: tr.scale_in(tr.active[2], failure=True))]:
+            before = host()
+            if kind == "scale-in":
+                held = tr.state
+            fetches[0] = 0
+            move()
+            assert fetches[0] == 0, (kind, fetches[0])
+            after = host()
+            assert all(a.dtype == b.dtype and np.array_equal(a, b)
+                       for a, b in zip(before, after)), kind
+        assert (len(tr.active), tr.tp) == (2, 1)
+
+        three = NamedSharding(Mesh(np.array(jax.devices()[:3]), ("data",)),
+                              P())
+        live[0] = True
+        jax.block_until_ready(jax.device_put(held, three))
+        assert fetches[0] >= 1, fetches[0]
+        print("OK on the devices", fetches[0])
+    """, devices=4)
+    assert "OK on the devices" in out
+
+
+def test_relayout_sharding_rules():
+    """A leaf is laid out anew only where some new block is held whole by
+    no device: on the same devices as the new sharding itself, onto other
+    devices as a replica on the old ones. Blocks held whole are copied,
+    whatever the devices; other devices' new shards, which a replica cannot
+    serve, are refused."""
+    out = _run("""
+        import jax, numpy as np, pytest
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from repro.elastic.trainer import relayout_sharding
+
+        d = jax.devices()
+        dp4 = NamedSharding(Mesh(np.array(d), ("data",)), P())
+        tp2 = Mesh(np.array(d).reshape(2, 2), ("data", "model"))
+        split = NamedSharding(tp2, P(None, "model"))
+        three = NamedSharding(Mesh(np.array(d[:3]), ("data",)), P())
+        assert relayout_sharding(dp4, three, (8, 6)) is None
+        assert relayout_sharding(NamedSharding(tp2, P()), three, (8, 5)) is None
+        assert relayout_sharding(dp4, split, (8, 6)) == split
+        assert relayout_sharding(split, dp4, (8, 6)) == dp4
+        assert relayout_sharding(split, three, (8, 6)) == NamedSharding(tp2, P())
+        halves = NamedSharding(Mesh(np.array(d[:2]), ("model",)),
+                               P(None, "model"))
+        assert relayout_sharding(split, halves, (8, 6)) is None
+        thirds = NamedSharding(Mesh(np.array(d[:3]), ("model",)),
+                               P(None, "model"))
+        with pytest.raises(ValueError):
+            relayout_sharding(split, thirds, (8, 6))
+        print("OK rules")
+    """, devices=4)
+    assert "OK rules" in out

@@ -194,7 +194,9 @@ def test_moves_bytes_and_wall_on_four_devices():
     """A join under replicate-only DP moves the whole state to the new chip;
     a dp -> tp reshard and a failure move nothing; (2, 2) gathering back to
     (3, 1) moves the halves each survivor lacks. `wall_s` is the move's
-    plan + codec + transfer spans."""
+    plan + codec + transfer spans. The reshard and the gather lay out anew
+    on their devices each leaf split over `model` (an even last dim); every
+    other leaf, and every leaf of a join or a failure, is copied whole."""
     out = _run("""
         import jax, numpy as np
         from repro.configs import get_config
@@ -211,6 +213,8 @@ def test_moves_bytes_and_wall_on_four_devices():
         full = sum(x.nbytes for x in leaves)
         halves = sum(x.nbytes // 2 for x in leaves
                      if x.ndim and x.shape[-1] % 2 == 0)
+        n = len(leaves)
+        even = sum(1 for x in leaves if x.ndim and x.shape[-1] % 2 == 0)
 
         step()
         ev = [tr.scale_out(codec="int8")]
@@ -243,6 +247,14 @@ def test_moves_bytes_and_wall_on_four_devices():
         assert rec.counter("chaos_move_bytes_total") == {
             ("scale-out",): 2.0 * full, ("reshard",): 0.0,
             ("scale-in",): 3.0 * halves, ("node-failure",): 0.0}
+        assert rec.counter("chaos_move_leaves_total") == {
+            ("scale-out", "relayout"): 0.0, ("scale-out", "copy"): 2.0 * n,
+            ("reshard", "relayout"): 1.0 * even,
+            ("reshard", "copy"): 1.0 * (n - even),
+            ("scale-in", "relayout"): 1.0 * even,
+            ("scale-in", "copy"): 1.0 * (n - even),
+            ("node-failure", "relayout"): 0.0,
+            ("node-failure", "copy"): 1.0 * n}
         compiles = [(c.attrs["n"], c.attrs["tp"])
                     for c in rec.named("chaos.compile")]
         assert compiles == [(2, 1), (3, 1), (4, 2)], compiles
